@@ -469,7 +469,7 @@ def _store_gather_speedup(iters: int = 5, quick: bool = False) -> None:
     _emit(
         f"store_gather_kernel_p{P}_m{Mk}",
         t_kernel * 1e6,
-        f"interpret=True gbps={kgbps:.4f}",
+        f"interpret={ops.interpret_mode()} gbps={kgbps:.4f}",
     )
 
 
@@ -517,16 +517,17 @@ def run(quick: bool = False):
     table = jax.random.normal(jax.random.PRNGKey(0), (4096, 512), jnp.float32)
     idx = jax.random.randint(jax.random.PRNGKey(1), (256,), 0, 4096)
     us = _time(lambda: ops.gather_rows(table, idx), iters=iters)
-    _emit("kernel_gather_rows_4096x512_g256", us, "interpret=True")
+    mode = f"interpret={ops.interpret_mode()}"
+    _emit("kernel_gather_rows_4096x512_g256", us, mode)
 
     idx2 = jax.random.randint(jax.random.PRNGKey(2), (64, 10), 0, 4096)
     us = _time(lambda: ops.gather_mean(table, idx2), iters=iters)
-    _emit("kernel_gather_mean_b64_k10", us, "interpret=True")
+    _emit("kernel_gather_mean_b64_k10", us, mode)
 
     scores = jax.random.uniform(jax.random.PRNGKey(4), (65536,), maxval=3.0)
     acc = jax.random.bernoulli(jax.random.PRNGKey(5), 0.4, (65536,))
     us = _time(lambda: ops.score_update(scores, acc), iters=iters)
-    _emit("kernel_score_update_64k", us, "interpret=True")
+    _emit("kernel_score_update_64k", us, mode)
 
     # The sampling plane's fused dedup: 8 PEs x 4k-slot sorted frontiers.
     rng = np.random.default_rng(6)
@@ -535,7 +536,7 @@ def run(quick: bool = False):
     )
     rem = jnp.asarray(rng.random((8, 4224)) < 0.5)
     us = _time(lambda: ops.frontier_unique_batch(keys, rem), iters=iters)
-    _emit("kernel_frontier_unique_batch_p8_m4224", us, "interpret=True")
+    _emit("kernel_frontier_unique_batch_p8_m4224", us, mode)
 
     _sampler_plane_speedup(iters=3 if quick else 5)
     _fused_step_speedup(iters=8 if quick else 12, quick=quick)
@@ -547,7 +548,7 @@ def run(quick: bool = False):
             jax.random.PRNGKey(3), (64 * 25, 256), jnp.float32
         )
         us = _time(lambda: ops.segment_sum_equal(data, 25), iters=iters)
-        _emit("kernel_segment_sum_s64_k25", us, "interpret=True")
+        _emit("kernel_segment_sum_s64_k25", us, mode)
 
         ks = jax.random.split(jax.random.PRNGKey(6), 4)
         q_lat = jax.random.normal(ks[0], (2, 16, 128)) * 0.3
@@ -560,7 +561,7 @@ def run(quick: bool = False):
             ),
             iters=iters,
         )
-        _emit("kernel_mla_flash_decode_s1024", us, "interpret=True")
+        _emit("kernel_mla_flash_decode_s1024", us, mode)
     return True
 
 

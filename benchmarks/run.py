@@ -55,6 +55,7 @@ label (e.g. ``--sweep p4 massivegnn``). Sweep options:
   ``python -m repro.trace replay/diff``.
 """
 
+import os
 import sys
 import time
 import traceback
@@ -232,7 +233,20 @@ def run_sweep_cli(selected: list[str]) -> int:
     return 0
 
 
+def _use_checkout_compile_cache() -> None:
+    """Keep JAX's persistent compile cache at one fixed, gitignored path
+    of the checkout, so later runs from it reuse compiled programs. An
+    explicit ``JAX_COMPILATION_CACHE_DIR`` is left to JAX."""
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        return
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir", os.path.join(root, ".jax_cache"))
+
+
 def main() -> int:
+    _use_checkout_compile_cache()
     selected = sys.argv[1:]
     if "--sweep" in selected:
         selected.remove("--sweep")

@@ -17,8 +17,8 @@ Inputs are the *sorted* keys; the neighbor-shift operand is built by the
 wrapper (a roll at the jnp level), so the kernel body is purely
 elementwise + reduce and tiles exactly like the scoring kernels.
 
-Grid: (tiles,) over an (8, 128)-aligned 2-D view, one partial count per
-tile reduced back to one count per PE.
+Grid: (tiles,) over an (8, 128)-aligned 2-D view, one ``(1, 128)`` row
+of per-lane partial counts per tile, reduced back to one count per PE.
 
 Catalog entry: ``docs/KERNELS.md#frontier_unique``.
 """
@@ -46,13 +46,13 @@ def _frontier_kernel(keys_ref, prev_ref, remote_ref, first_ref, rmask_ref,
     rmask = first * remote_ref[...]
     first_ref[...] = first
     rmask_ref[...] = rmask
-    ucount_ref[0, 0] = jnp.sum(first)
-    rcount_ref[0, 0] = jnp.sum(rmask)
+    ucount_ref[...] = jnp.sum(first, axis=0, keepdims=True)
+    rcount_ref[...] = jnp.sum(rmask, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def frontier_unique_batch(
-    sorted_keys: jax.Array, is_remote: jax.Array, *, interpret: bool = True
+    sorted_keys: jax.Array, is_remote: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused unique + remote masks over row-sorted frontiers.
 
@@ -86,7 +86,7 @@ def frontier_unique_batch(
     r2 = r2.reshape(tiles * TILE_ROWS, LANES)
 
     block = pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0))
-    count = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    count = pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))
     first, rmask, ucount, rcount = pl.pallas_call(
         _frontier_kernel,
         grid=(tiles,),
@@ -95,15 +95,15 @@ def frontier_unique_batch(
         out_shape=[
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.int32),
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(k2, p2, r2)
     first = first.reshape(P, -1)[:, :M].astype(bool)
     rmask = rmask.reshape(P, -1)[:, :M].astype(bool)
-    ucount = jnp.sum(ucount.reshape(P, tiles_per_pe), axis=1)
-    rcount = jnp.sum(rcount.reshape(P, tiles_per_pe), axis=1)
+    ucount = jnp.sum(ucount.reshape(P, -1), axis=1)
+    rcount = jnp.sum(rcount.reshape(P, -1), axis=1)
     return first, rmask, ucount, rcount
 
 
@@ -126,8 +126,8 @@ def _frontier_kernel_wide(
     rmask = first * remote_ref[...]
     first_ref[...] = first
     rmask_ref[...] = rmask
-    ucount_ref[0, 0] = jnp.sum(first)
-    rcount_ref[0, 0] = jnp.sum(rmask)
+    ucount_ref[...] = jnp.sum(first, axis=0, keepdims=True)
+    rcount_ref[...] = jnp.sum(rmask, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -136,7 +136,7 @@ def frontier_unique_batch_wide(
     sorted_hi: jax.Array,
     is_remote: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Wide-id twin of :func:`frontier_unique_batch`: rows are sorted
     ``(hi, lo)`` int32 word-pair planes (numeric 64-bit order under the
@@ -173,7 +173,7 @@ def frontier_unique_batch_wide(
     r2 = r2.reshape(tiles * TILE_ROWS, LANES)
 
     block = pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0))
-    count = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    count = pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))
     first, rmask, ucount, rcount = pl.pallas_call(
         _frontier_kernel_wide,
         grid=(tiles,),
@@ -182,13 +182,13 @@ def frontier_unique_batch_wide(
         out_shape=[
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.int32),
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(kl2, kh2, pl2, ph2, r2)
     first = first.reshape(P, -1)[:, :M].astype(bool)
     rmask = rmask.reshape(P, -1)[:, :M].astype(bool)
-    ucount = jnp.sum(ucount.reshape(P, tiles_per_pe), axis=1)
-    rcount = jnp.sum(rcount.reshape(P, tiles_per_pe), axis=1)
+    ucount = jnp.sum(ucount.reshape(P, -1), axis=1)
+    rcount = jnp.sum(rcount.reshape(P, -1), axis=1)
     return first, rmask, ucount, rcount

@@ -21,18 +21,23 @@ decision for a step is computed on host between probes — see the
 pipeline rotation in :class:`repro.runtime.stage.FusedFetchStage`.
 
 Grid: ``(P,)`` — one program per trainer PE; each program owns
-lane-padded ``(1, C)`` state blocks plus ``(1, M)`` query and ``(1, K)``
-candidate blocks, and builds dense ``(K, C)`` / ``(M, C)`` comparison
-tiles in VMEM (cumulative-sum slot ranking + one-hot candidate→slot
-matching — no ragged Python loop; the host pairs placed candidates
-with slots from the returned per-slot fill ranks).
+lane-padded ``(1, C)`` state rows plus ``(1, M)`` query and ``(1, K)``
+candidate rows (every ``(P, W)`` operand rides as ``(P, 1, W)`` with a
+``(None, 1, W)`` block, which is what Mosaic's block-shape rule admits),
+and builds dense ``(K, C)`` / ``(K, K)`` / ``(M, C)`` comparison tiles in
+VMEM (prefix-count slot ranking + one-hot candidate→slot matching — no
+ragged Python loop; the host pairs placed candidates with slots from
+the returned per-slot fill ranks). Rows turn into columns through an
+aligned ``(128, W)`` transpose and prefix counts are a scan of lane
+rotations, since Mosaic lowers neither lane→sublane reshapes nor
+``cumsum``. Those dense tiles bound the launch: see
+``docs/KERNELS.md#fused_step`` for the VMEM ceiling.
 
 Ids are int32 (-1 = empty/padding); the public dispatcher
 :func:`repro.kernels.ops.fused_step_batch` guards the int64→int32 range
-and falls back to the jnp oracle :func:`repro.kernels.ref.fused_step`
-with identical outputs. Parity: ``tests/test_fused_step.py`` (staged
-``PrefetchEngine`` ground truth + hypothesis suite). Catalog:
-``docs/KERNELS.md#fused_step``.
+and routes wider ids through the two-word twin. Parity:
+``tests/test_fused_step.py`` (staged ``PrefetchEngine`` ground truth +
+hypothesis suite); v5e compile checks: ``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -42,11 +47,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import scoring
 from . import ref as _ref
 
 LANES = 128
+
+#: Scoped-VMEM budget of one fused launch. The dense comparison tiles
+#: outgrow Mosaic's 16 MB default (16.4 MB at P=4, C=1294, Mt=8352); a
+#: v5e core has 128 MiB of VMEM, and this leaves headroom below it.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+
+def _col(x):
+    """``(1, W)`` row → ``(W, 1)`` column via an aligned ``(128, W)``
+    transpose (W is lane-padded to a multiple of 128)."""
+    return jnp.broadcast_to(x, (LANES, x.shape[1])).T[:, :1]
+
+
+def _row(x):
+    """``(W, 1)`` column → ``(1, W)`` row; inverse of :func:`_col`."""
+    return jnp.broadcast_to(x, (x.shape[0], LANES)).T[:1, :]
+
+
+def _prefix_count(mask):
+    """Inclusive prefix sum of a ``(1, W)`` bool row as int32: a
+    Hillis–Steele scan over lane rotations (``log2 W`` steps)."""
+    x = mask.astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < x.shape[1]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0)
+        shift *= 2
+    return x
+
+
+def _count(mask):
+    """Number of set lanes of a ``(1, W)`` bool row, as ``(1, 1)``."""
+    return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def _fused_body(
@@ -73,7 +112,8 @@ def _fused_body(
     mode,
     initial_score,
 ):
-    """Single-PE fused round; shapes (1, C) / (1, M) / (1, K).
+    """Single-PE fused round over ``(1, C)`` / ``(1, M)`` / ``(1, K)``
+    rows; the three gates are ``(1, 1)`` bools.
 
     With the optional ``*_hi`` planes present (the two-word id
     encoding — ``kernels/ref.py`` ``WIDE_SHIFT``), every id compare is
@@ -100,22 +140,18 @@ def _fused_body(
     acc1 = jnp.logical_and(a, jnp.logical_not(active_score))
 
     # -- 2. replacement round (replace_round) -------------------------- #
-    cand_t = cand.reshape(K, 1)
-    eq_m = cand_t == ids.reshape(1, C)
+    # Candidates run down the sublanes, (K, 1), against (1, C) slots.
+    cand_t = _col(cand)
+    cand_hi_t = _col(cand_hi) if wide else None
+    eq_m = cand_t == ids
     if wide:
-        eq_m = jnp.logical_and(
-            eq_m, cand_hi.reshape(K, 1) == ids_hi.reshape(1, C)
-        )
-    member = jnp.any(
-        jnp.logical_and(eq_m, v.reshape(1, C)), axis=1
-    ).reshape(1, K)
+        eq_m = jnp.logical_and(eq_m, cand_hi_t == ids_hi)
+    member = jnp.any(jnp.logical_and(eq_m, v), axis=1, keepdims=True)
     # First-occurrence dedup (`_unique_preserve_order` in-kernel): a
     # candidate equal to an earlier position is never fresh.
-    eq_d = cand_t == cand.reshape(1, K)
+    eq_d = cand_t == cand
     if wide:
-        eq_d = jnp.logical_and(
-            eq_d, cand_hi.reshape(K, 1) == cand_hi.reshape(1, K)
-        )
+        eq_d = jnp.logical_and(eq_d, cand_hi_t == cand_hi)
     dup = jnp.any(
         jnp.logical_and(
             eq_d,
@@ -123,41 +159,38 @@ def _fused_body(
             < jax.lax.broadcasted_iota(jnp.int32, (K, K), 0),
         ),
         axis=1,
-    ).reshape(1, K)
-    cand_ok = (cand_hi >= 0) if wide else (cand >= 0)
-    fresh = jnp.logical_and(
+        keepdims=True,
+    )
+    cand_ok = (cand_hi_t >= 0) if wide else (cand_t >= 0)
+    fresh_t = jnp.logical_and(
         jnp.logical_and(cand_ok, jnp.logical_not(member)),
         jnp.logical_and(jnp.logical_not(dup), do_replace),
     )
+    fresh = _row(fresh_t.astype(jnp.int32)) != 0
     free = jnp.logical_and(jnp.logical_not(v), incap)
     stale = jnp.logical_and(v, s1 < jnp.float32(threshold))
-    n_free = jnp.sum(free.astype(jnp.int32))
-    free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
-    stale_rank = n_free + jnp.cumsum(stale.astype(jnp.int32), axis=1) - 1
+    n_free = _count(free)
+    free_rank = _prefix_count(free) - 1
+    stale_rank = n_free + _prefix_count(stale) - 1
     big = jnp.int32(C + K + 1)
     slot_pos = jnp.where(free, free_rank, jnp.where(stale, stale_rank, big))
-    fresh_rank = jnp.where(
-        fresh, jnp.cumsum(fresh.astype(jnp.int32), axis=1) - 1, big + 1
-    )
+    fresh_rank = jnp.where(fresh, _prefix_count(fresh) - 1, big + 1)
     n_place = jnp.where(
         do_replace,
-        jnp.minimum(
-            n_free + jnp.sum(stale.astype(jnp.int32)),
-            jnp.sum(fresh.astype(jnp.int32)),
-        ),
+        jnp.minimum(n_free + _count(stale), _count(fresh)),
         0,
     )
     placed = jnp.logical_and(fresh, fresh_rank < n_place)
     filled = slot_pos < n_place
     match = jnp.logical_and(
-        placed.reshape(K, 1), fresh_rank.reshape(K, 1) == slot_pos.reshape(1, C)
+        _col(placed.astype(jnp.int32)) != 0, _col(fresh_rank) == slot_pos
     )
-    new_id = jnp.sum(jnp.where(match, cand_t, 0), axis=0).reshape(1, C)
+    new_id = jnp.sum(jnp.where(match, cand_t, 0), axis=0, keepdims=True)
     ids2 = jnp.where(filled, new_id, ids)
     if wide:
         new_id_hi = jnp.sum(
-            jnp.where(match, cand_hi.reshape(K, 1), 0), axis=0
-        ).reshape(1, C)
+            jnp.where(match, cand_hi_t, 0), axis=0, keepdims=True
+        )
         ids2_hi = jnp.where(filled, new_id_hi, ids_hi)
     else:
         ids2_hi = None
@@ -165,44 +198,85 @@ def _fused_body(
     v2 = jnp.logical_or(v, filled)
     if w is not None:
         new_w = jnp.sum(
-            jnp.where(match, cand_w.reshape(K, 1), jnp.float32(0.0)), axis=0
-        ).reshape(1, C)
+            jnp.where(match, _col(cand_w), jnp.float32(0.0)),
+            axis=0,
+            keepdims=True,
+        )
         w2 = jnp.where(filled, new_w, w)
     else:
         w2 = None
     acc2 = jnp.logical_and(acc1, jnp.logical_not(filled))
 
     # -- 3. membership probe of the next round (lookup) ---------------- #
-    q_t = q.reshape(M, 1)
-    eq_q = q_t == ids2.reshape(1, C)
+    q_t = _col(q)
+    eq_q = q_t == ids2
     if wide:
-        eq_q = jnp.logical_and(
-            eq_q, q_hi.reshape(M, 1) == ids2_hi.reshape(1, C)
-        )
-    q_ok = (q_hi.reshape(M, 1) >= 0) if wide else (q_t >= 0)
+        q_hi_t = _col(q_hi)
+        eq_q = jnp.logical_and(eq_q, q_hi_t == ids2_hi)
+    q_ok = (q_hi_t >= 0) if wide else (q_t >= 0)
     qhit = jnp.logical_and(
-        jnp.logical_and(eq_q, v2.reshape(1, C)),
+        jnp.logical_and(eq_q, v2),
         jnp.logical_and(q_ok, active_probe),
     )
-    hit = jnp.any(qhit, axis=1).reshape(1, M)
+    hit_t = jnp.any(qhit, axis=1, keepdims=True)
     slot_iota_mc = jax.lax.broadcasted_iota(jnp.int32, (M, C), 1)
-    hit_slot = jnp.where(
-        hit, jnp.sum(jnp.where(qhit, slot_iota_mc, 0), axis=1).reshape(1, M), -1
+    hit_slot_t = jnp.where(
+        hit_t,
+        jnp.sum(jnp.where(qhit, slot_iota_mc, 0), axis=1, keepdims=True),
+        -1,
     )
-    acc3 = jnp.logical_or(acc2, jnp.any(qhit, axis=0).reshape(1, C))
+    hit = _row(hit_t.astype(jnp.int32)) != 0
+    hit_slot = _row(hit_slot_t)
+    acc3 = jnp.logical_or(acc2, jnp.any(qhit, axis=0, keepdims=True))
     return ids2, ids2_hi, s2, v2, acc3, w2, hit, hit_slot, placed, slot_pos
 
 
-def _make_fused_kernel(
-    increment,
-    decay,
-    threshold,
-    score_cap,
-    mode,
-    initial_score,
-    weighted,
-    wide=False,
-):
+
+
+def _padded(n):
+    """Lane-padded width of an ``n``-wide row: a multiple of 128, and at
+    least one lane tile, so an empty row still gives the grid a block."""
+    return max(LANES, -(-n // LANES) * LANES)
+
+
+def _pad_lanes(x, constant):
+    pad = _padded(x.shape[1]) - x.shape[1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, ((0, 0), (0, pad)), constant_values=constant)
+
+
+def _gate_rows(active_score, do_replace, active_probe):
+    """The three ``(P,)`` gate vectors as one lane-padded ``(P, 128)``
+    int32 operand (lanes 0..2)."""
+    gates = jnp.stack(
+        [
+            active_score.astype(jnp.int32),
+            do_replace.astype(jnp.int32),
+            active_probe.astype(jnp.int32),
+        ],
+        axis=1,
+    )
+    return _pad_lanes(gates, 0)
+
+
+def _read_gates(gates):
+    """``(active_score, do_replace, active_probe)`` as ``(1, 1)`` bools."""
+    return gates[:, 0:1] != 0, gates[:, 1:2] != 0, gates[:, 2:3] != 0
+
+
+def _constants(increment, decay, threshold, score_cap, mode, initial_score):
+    return dict(
+        increment=float(increment),
+        decay=float(decay),
+        threshold=float(threshold),
+        score_cap=float(score_cap),
+        mode=mode,
+        initial_score=float(initial_score),
+    )
+
+
+def _make_fused_kernel(constants, weighted, wide):
     """Kernel factory for the fused score→replace→probe launch.
 
     The operand list is computed from the (weighted, wide) configuration
@@ -249,18 +323,11 @@ def _make_fused_kernel(
             q,
             cand,
             cand_w,
-            gates[0, 0] != 0,
-            gates[0, 1] != 0,
-            gates[0, 2] != 0,
+            *_read_gates(gates),
             ids_hi=ids_hi,
             q_hi=q_hi,
             cand_hi=cand_hi,
-            increment=increment,
-            decay=decay,
-            threshold=threshold,
-            score_cap=score_cap,
-            mode=mode,
-            initial_score=initial_score,
+            **constants,
         )
         vals = [ids2]
         if wide:
@@ -280,315 +347,7 @@ def _make_fused_kernel(
     return kernel
 
 
-def _pad_lanes(x, width, constant):
-    pad = (width - x.shape[1] % width) % width
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, pad)), constant_values=constant)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "increment",
-        "decay",
-        "threshold",
-        "score_cap",
-        "mode",
-        "initial_score",
-        "interpret",
-    ),
-)
-def fused_step_pallas(
-    ids,
-    scores,
-    valid,
-    accessed,
-    in_capacity,
-    weights,
-    queries,
-    cand,
-    cand_weights,
-    active_score,
-    do_replace,
-    active_probe,
-    *,
-    increment: float = float(scoring.ACCESS_INCREMENT),
-    decay: float = float(scoring.DECAY_FACTOR),
-    threshold: float = float(scoring.STALE_THRESHOLD),
-    score_cap: float = 4.0,
-    mode: str = "accumulate",
-    initial_score: float = float(scoring.INITIAL_SCORE),
-    interpret: bool = True,
-):
-    """Pallas twin of :func:`repro.kernels.ref.fused_step` (same signature
-    and outputs; see that oracle for the full semantics).
-
-    State blocks are lane-padded to multiples of 128 with engine padding
-    semantics (``valid=False``, ``in_capacity=False``, ``id=-1``) so
-    padded slots are never free, never stale, and never match a query;
-    ``queries``/``cand`` pad with -1 (matches nothing). Dispatch via
-    :func:`repro.kernels.ops.fused_step_batch`; catalog entry
-    ``docs/KERNELS.md#fused_step``.
-    """
-    P, C = ids.shape
-    M = queries.shape[1]
-    K = cand.shape[1]
-    weighted = weights is not None
-
-    ids_p = _pad_lanes(ids.astype(jnp.int32), LANES, -1)
-    s_p = _pad_lanes(scores.astype(jnp.float32), LANES, 1.0)
-    v_p = _pad_lanes(valid.astype(jnp.int32), LANES, 0)
-    a_p = _pad_lanes(accessed.astype(jnp.int32), LANES, 0)
-    cap_p = _pad_lanes(in_capacity.astype(jnp.int32), LANES, 0)
-    q_p = _pad_lanes(queries.astype(jnp.int32), LANES, -1)
-    c_p = _pad_lanes(cand.astype(jnp.int32), LANES, -1)
-    gates = jnp.stack(
-        [
-            active_score.astype(jnp.int32),
-            do_replace.astype(jnp.int32),
-            active_probe.astype(jnp.int32),
-        ],
-        axis=1,
-    )
-    gates = _pad_lanes(gates, LANES, 0)
-    Cp, Mp, Kp = ids_p.shape[1], q_p.shape[1], c_p.shape[1]
-
-    def spec(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0))
-
-    operands = [ids_p, s_p, v_p, a_p, cap_p]
-    if weighted:
-        operands.append(_pad_lanes(weights.astype(jnp.float32), LANES, 1.0))
-    operands += [q_p, c_p]
-    if weighted:
-        operands.append(
-            _pad_lanes(cand_weights.astype(jnp.float32), LANES, 0.0)
-        )
-    operands.append(gates)
-
-    out_specs = [spec(Cp)] * (5 if weighted else 4) + [
-        spec(Mp),
-        spec(Mp),
-        spec(Kp),
-        spec(Cp),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-    if weighted:
-        out_shape.append(jax.ShapeDtypeStruct((P, Cp), jnp.float32))
-    out_shape += [
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Kp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-
-    outs = pl.pallas_call(
-        _make_fused_kernel(
-            float(increment),
-            float(decay),
-            float(threshold),
-            float(score_cap),
-            mode,
-            float(initial_score),
-            weighted,
-        ),
-        grid=(P,),
-        in_specs=[spec(x.shape[1]) for x in operands],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
-
-    if weighted:
-        ids2, s2, v2, acc3, w2, hit, hit_slot, placed, slot_pos = outs
-        w_out = w2[:, :C]
-    else:
-        ids2, s2, v2, acc3, hit, hit_slot, placed, slot_pos = outs
-        w_out = None
-    valid2 = v2[:, :C] != 0
-    placed_b = placed[:, :K] != 0
-    return (
-        ids2[:, :C],
-        s2[:, :C],
-        valid2,
-        acc3[:, :C] != 0,
-        w_out,
-        hit[:, :M] != 0,
-        hit_slot[:, :M],
-        placed_b,
-        # The kernel's `big` sentinel uses lane-padded C/K; clamp to the
-        # unpadded sentinel so outputs are bit-identical to the oracle.
-        jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1)),
-        jnp.sum(placed_b.astype(jnp.int32), axis=1),
-        jnp.sum(valid2.astype(jnp.int32), axis=1),
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "increment",
-        "decay",
-        "threshold",
-        "score_cap",
-        "mode",
-        "initial_score",
-        "interpret",
-    ),
-)
-def fused_step_wide_pallas(
-    ids,
-    ids_hi,
-    scores,
-    valid,
-    accessed,
-    in_capacity,
-    weights,
-    queries,
-    queries_hi,
-    cand,
-    cand_hi,
-    cand_weights,
-    active_score,
-    do_replace,
-    active_probe,
-    *,
-    increment: float = float(scoring.ACCESS_INCREMENT),
-    decay: float = float(scoring.DECAY_FACTOR),
-    threshold: float = float(scoring.STALE_THRESHOLD),
-    score_cap: float = 4.0,
-    mode: str = "accumulate",
-    initial_score: float = float(scoring.INITIAL_SCORE),
-    interpret: bool = True,
-):
-    """Pallas twin of :func:`repro.kernels.ref.fused_step_wide` — the
-    two-word ``(hi, lo)`` id encoding in the same single launch.
-
-    Both planes lane-pad with -1 (the empty-pair sentinel), so padded
-    slots/queries/candidates stay invalid under the pair semantics
-    (validity is ``hi >= 0``). Returns the 12-tuple of the oracle with
-    ``ids2_hi`` after ``ids2``. Dispatch via
-    :func:`repro.kernels.ops.fused_step_wide_batch`.
-    """
-    P, C = ids.shape
-    M = queries.shape[1]
-    K = cand.shape[1]
-    weighted = weights is not None
-
-    ids_p = _pad_lanes(ids.astype(jnp.int32), LANES, -1)
-    idshi_p = _pad_lanes(ids_hi.astype(jnp.int32), LANES, -1)
-    s_p = _pad_lanes(scores.astype(jnp.float32), LANES, 1.0)
-    v_p = _pad_lanes(valid.astype(jnp.int32), LANES, 0)
-    a_p = _pad_lanes(accessed.astype(jnp.int32), LANES, 0)
-    cap_p = _pad_lanes(in_capacity.astype(jnp.int32), LANES, 0)
-    q_p = _pad_lanes(queries.astype(jnp.int32), LANES, -1)
-    qhi_p = _pad_lanes(queries_hi.astype(jnp.int32), LANES, -1)
-    c_p = _pad_lanes(cand.astype(jnp.int32), LANES, -1)
-    chi_p = _pad_lanes(cand_hi.astype(jnp.int32), LANES, -1)
-    gates = jnp.stack(
-        [
-            active_score.astype(jnp.int32),
-            do_replace.astype(jnp.int32),
-            active_probe.astype(jnp.int32),
-        ],
-        axis=1,
-    )
-    gates = _pad_lanes(gates, LANES, 0)
-    Cp, Mp, Kp = ids_p.shape[1], q_p.shape[1], c_p.shape[1]
-
-    def spec(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0))
-
-    operands = [ids_p, idshi_p, s_p, v_p, a_p, cap_p]
-    if weighted:
-        operands.append(_pad_lanes(weights.astype(jnp.float32), LANES, 1.0))
-    operands += [q_p, qhi_p, c_p, chi_p]
-    if weighted:
-        operands.append(
-            _pad_lanes(cand_weights.astype(jnp.float32), LANES, 0.0)
-        )
-    operands.append(gates)
-
-    out_specs = [spec(Cp)] * (6 if weighted else 5) + [
-        spec(Mp),
-        spec(Mp),
-        spec(Kp),
-        spec(Cp),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-    if weighted:
-        out_shape.append(jax.ShapeDtypeStruct((P, Cp), jnp.float32))
-    out_shape += [
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Kp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-
-    outs = pl.pallas_call(
-        _make_fused_kernel(
-            float(increment),
-            float(decay),
-            float(threshold),
-            float(score_cap),
-            mode,
-            float(initial_score),
-            weighted,
-            wide=True,
-        ),
-        grid=(P,),
-        in_specs=[spec(x.shape[1]) for x in operands],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
-
-    if weighted:
-        ids2, ids2_hi2, s2, v2, acc3, w2, hit, hit_slot, placed, slot_pos = outs
-        w_out = w2[:, :C]
-    else:
-        ids2, ids2_hi2, s2, v2, acc3, hit, hit_slot, placed, slot_pos = outs
-        w_out = None
-    valid2 = v2[:, :C] != 0
-    placed_b = placed[:, :K] != 0
-    return (
-        ids2[:, :C],
-        ids2_hi2[:, :C],
-        s2[:, :C],
-        valid2,
-        acc3[:, :C] != 0,
-        w_out,
-        hit[:, :M] != 0,
-        hit_slot[:, :M],
-        placed_b,
-        jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1)),
-        jnp.sum(placed_b.astype(jnp.int32), axis=1),
-        jnp.sum(valid2.astype(jnp.int32), axis=1),
-    )
-
-
-def _make_frontier_kernel(
-    increment,
-    decay,
-    threshold,
-    score_cap,
-    mode,
-    initial_score,
-    weighted,
-    wide=False,
-):
+def _make_frontier_kernel(constants, weighted, wide):
     """Kernel factory for the single-launch frontier step: the fused
     score→replace→probe body of :func:`_make_fused_kernel` with the
     frontier dedup folded in front (first-occurrence + remote masks
@@ -651,18 +410,11 @@ def _make_frontier_kernel(
             q,
             cand,
             cand_w,
-            gates[0, 0] != 0,
-            gates[0, 1] != 0,
-            gates[0, 2] != 0,
+            *_read_gates(gates),
             ids_hi=ids_hi,
             q_hi=q_hi,
             cand_hi=cand_hi,
-            increment=increment,
-            decay=decay,
-            threshold=threshold,
-            score_cap=score_cap,
-            mode=mode,
-            initial_score=initial_score,
+            **constants,
         )
         code = jnp.where(
             remote,
@@ -682,19 +434,349 @@ def _make_frontier_kernel(
     return kernel
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "cand_cap",
-        "increment",
-        "decay",
-        "threshold",
-        "score_cap",
-        "mode",
-        "initial_score",
-        "interpret",
-    ),
+def _pallas_rows(kernel, operands, outs, *, interpret):
+    """One ``grid=(P,)`` launch over per-PE rows.
+
+    Every lane-padded ``(P, W)`` operand rides as ``(P, 1, W)`` with a
+    ``(None, 1, W)`` block, so each program sees ``(1, W)`` rows whose
+    last two block dims equal the array's. ``outs`` lists the ``(width,
+    dtype)`` of each ``(P, width)`` output; returns them as ``(P, width)``
+    arrays."""
+    P = operands[0].shape[0]
+
+    def spec(width):
+        return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0))
+
+    res = pl.pallas_call(
+        kernel,
+        grid=(P,),
+        in_specs=[spec(x.shape[1]) for x in operands],
+        out_specs=[spec(width) for width, _ in outs],
+        out_shape=[
+            jax.ShapeDtypeStruct((P, 1, width), dt) for width, dt in outs
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
+        interpret=interpret,
+    )(*[x[:, None, :] for x in operands])
+    return [r[:, 0, :] for r in res]
+
+
+def _state_operands(ids, ids_hi, scores, valid, accessed, in_capacity, weights):
+    """Lane-padded buffer state with engine padding semantics
+    (``valid=False``, ``in_capacity=False``, ``id=-1``): padded slots are
+    never free, never stale, and never match a query."""
+    ops = [_pad_lanes(ids.astype(jnp.int32), -1)]
+    if ids_hi is not None:
+        ops.append(_pad_lanes(ids_hi.astype(jnp.int32), -1))
+    ops += [
+        _pad_lanes(scores.astype(jnp.float32), 1.0),
+        _pad_lanes(valid.astype(jnp.int32), 0),
+        _pad_lanes(accessed.astype(jnp.int32), 0),
+        _pad_lanes(in_capacity.astype(jnp.int32), 0),
+    ]
+    if weights is not None:
+        ops.append(_pad_lanes(weights.astype(jnp.float32), 1.0))
+    return ops
+
+
+def _state_outs(C, wide, weighted):
+    Cp = _padded(C)
+    outs = [(Cp, jnp.int32)] * (2 if wide else 1)
+    outs += [(Cp, jnp.float32), (Cp, jnp.int32), (Cp, jnp.int32)]
+    if weighted:
+        outs.append((Cp, jnp.float32))
+    return outs
+
+
+def _take_state(it, C, wide, weighted):
+    """Unpad the state outputs: ``(ids2, ids2_hi, s2, valid2, acc3,
+    w2)`` with None for the planes the configuration lacks."""
+    ids2 = next(it)[:, :C]
+    ids2_hi = next(it)[:, :C] if wide else None
+    s2 = next(it)[:, :C]
+    valid2 = next(it)[:, :C] != 0
+    acc3 = next(it)[:, :C] != 0
+    w2 = next(it)[:, :C] if weighted else None
+    return ids2, ids2_hi, s2, valid2, acc3, w2
+
+
+def _fused_step_core(
+    ids,
+    ids_hi,
+    scores,
+    valid,
+    accessed,
+    in_capacity,
+    weights,
+    queries,
+    queries_hi,
+    cand,
+    cand_hi,
+    cand_weights,
+    gates,
+    *,
+    constants,
+    interpret,
+):
+    """Shared narrow/wide launch of the staged fused step: returns the
+    wide oracle's 12-tuple (``ids2_hi`` None on the narrow path)."""
+    P, C = ids.shape
+    M = queries.shape[1]
+    K = cand.shape[1]
+    wide = ids_hi is not None
+    weighted = weights is not None
+    operands = _state_operands(
+        ids, ids_hi, scores, valid, accessed, in_capacity, weights
+    )
+    operands.append(_pad_lanes(queries.astype(jnp.int32), -1))
+    if wide:
+        operands.append(_pad_lanes(queries_hi.astype(jnp.int32), -1))
+    operands.append(_pad_lanes(cand.astype(jnp.int32), -1))
+    if wide:
+        operands.append(_pad_lanes(cand_hi.astype(jnp.int32), -1))
+    if weighted:
+        operands.append(_pad_lanes(cand_weights.astype(jnp.float32), 0.0))
+    operands.append(gates)
+    outs = _state_outs(C, wide, weighted) + [
+        (_padded(M), jnp.int32),
+        (_padded(M), jnp.int32),
+        (_padded(K), jnp.int32),
+        (_padded(C), jnp.int32),
+    ]
+    it = iter(
+        _pallas_rows(
+            _make_fused_kernel(constants, weighted, wide),
+            operands,
+            outs,
+            interpret=interpret,
+        )
+    )
+    ids2, ids2_hi, s2, valid2, acc3, w2 = _take_state(it, C, wide, weighted)
+    hit, hit_slot, placed, slot_pos = it
+    placed_b = placed[:, :K] != 0
+    return (
+        ids2,
+        ids2_hi,
+        s2,
+        valid2,
+        acc3,
+        w2,
+        hit[:, :M] != 0,
+        hit_slot[:, :M],
+        placed_b,
+        # The kernel's `big` sentinel uses lane-padded C/K; clamp to the
+        # unpadded sentinel so outputs are bit-identical to the oracle.
+        jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1)),
+        jnp.sum(placed_b.astype(jnp.int32), axis=1),
+        jnp.sum(valid2.astype(jnp.int32), axis=1),
+    )
+
+
+_STATICS = (
+    "increment",
+    "decay",
+    "threshold",
+    "score_cap",
+    "mode",
+    "initial_score",
+    "interpret",
 )
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def fused_step_pallas(
+    ids,
+    scores,
+    valid,
+    accessed,
+    in_capacity,
+    weights,
+    queries,
+    cand,
+    cand_weights,
+    active_score,
+    do_replace,
+    active_probe,
+    *,
+    increment: float = float(scoring.ACCESS_INCREMENT),
+    decay: float = float(scoring.DECAY_FACTOR),
+    threshold: float = float(scoring.STALE_THRESHOLD),
+    score_cap: float = 4.0,
+    mode: str = "accumulate",
+    initial_score: float = float(scoring.INITIAL_SCORE),
+    interpret: bool,
+):
+    """Pallas twin of :func:`repro.kernels.ref.fused_step` (same signature
+    and outputs; see that oracle for the full semantics).
+
+    State rows are lane-padded to multiples of 128 — at least one lane
+    tile, so a zero-capacity cluster is an all-padding row — with engine
+    padding semantics; ``queries``/``cand`` pad with -1 (matches
+    nothing). Dispatch via :func:`repro.kernels.ops.fused_step_batch`;
+    catalog entry ``docs/KERNELS.md#fused_step``.
+    """
+    out = _fused_step_core(
+        ids,
+        None,
+        scores,
+        valid,
+        accessed,
+        in_capacity,
+        weights,
+        queries,
+        None,
+        cand,
+        None,
+        cand_weights,
+        _gate_rows(active_score, do_replace, active_probe),
+        constants=_constants(
+            increment, decay, threshold, score_cap, mode, initial_score
+        ),
+        interpret=interpret,
+    )
+    return out[:1] + out[2:]
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def fused_step_wide_pallas(
+    ids,
+    ids_hi,
+    scores,
+    valid,
+    accessed,
+    in_capacity,
+    weights,
+    queries,
+    queries_hi,
+    cand,
+    cand_hi,
+    cand_weights,
+    active_score,
+    do_replace,
+    active_probe,
+    *,
+    increment: float = float(scoring.ACCESS_INCREMENT),
+    decay: float = float(scoring.DECAY_FACTOR),
+    threshold: float = float(scoring.STALE_THRESHOLD),
+    score_cap: float = 4.0,
+    mode: str = "accumulate",
+    initial_score: float = float(scoring.INITIAL_SCORE),
+    interpret: bool,
+):
+    """Pallas twin of :func:`repro.kernels.ref.fused_step_wide` — the
+    two-word ``(hi, lo)`` id encoding in the same single launch.
+
+    Both planes lane-pad with -1 (the empty-pair sentinel), so padded
+    slots/queries/candidates stay invalid under the pair semantics
+    (validity is ``hi >= 0``). Returns the 12-tuple of the oracle with
+    ``ids2_hi`` after ``ids2``. Dispatch via
+    :func:`repro.kernels.ops.fused_step_wide_batch`.
+    """
+    return _fused_step_core(
+        ids,
+        ids_hi,
+        scores,
+        valid,
+        accessed,
+        in_capacity,
+        weights,
+        queries,
+        queries_hi,
+        cand,
+        cand_hi,
+        cand_weights,
+        _gate_rows(active_score, do_replace, active_probe),
+        constants=_constants(
+            increment, decay, threshold, score_cap, mode, initial_score
+        ),
+        interpret=interpret,
+    )
+
+
+def _frontier_core(
+    ids,
+    ids_hi,
+    scores,
+    valid,
+    accessed,
+    in_capacity,
+    weights,
+    sk,
+    sk_hi,
+    prev,
+    prev_hi,
+    rem,
+    cand,
+    cand_hi,
+    cw,
+    gates,
+    *,
+    constants,
+    interpret,
+):
+    """Shared narrow/wide per-PE core of the frontier step (padding:
+    ``sk``/``prev``/``cand`` → -1, masks → 0 — a padded position is
+    never first, never remote, never fresh). Returns ``(ids2, ids2_hi,
+    s2, valid2, acc3, w2, code, placed, slot_pos, n_place, n_valid)``."""
+    C = ids.shape[1]
+    Mt = sk.shape[1]
+    K = cand.shape[1]
+    wide = ids_hi is not None
+    weighted = weights is not None
+    operands = _state_operands(
+        ids, ids_hi, scores, valid, accessed, in_capacity, weights
+    )
+    operands.append(_pad_lanes(sk, -1))
+    if wide:
+        operands.append(_pad_lanes(sk_hi, -1))
+    operands.append(_pad_lanes(prev, -1))
+    if wide:
+        operands.append(_pad_lanes(prev_hi, -1))
+    operands += [
+        _pad_lanes(rem.astype(jnp.int32), 0),
+        _pad_lanes(cand.astype(jnp.int32), -1),
+    ]
+    if wide:
+        operands.append(_pad_lanes(cand_hi.astype(jnp.int32), -1))
+    if weighted:
+        operands.append(_pad_lanes(cw.astype(jnp.float32), 0.0))
+    operands.append(gates)
+    outs = _state_outs(C, wide, weighted) + [
+        (_padded(Mt), jnp.int32),
+        (_padded(K), jnp.int32),
+        (_padded(C), jnp.int32),
+    ]
+    it = iter(
+        _pallas_rows(
+            _make_frontier_kernel(constants, weighted, wide),
+            operands,
+            outs,
+            interpret=interpret,
+        )
+    )
+    ids2, ids2_hi, s2, valid2, acc3, w2 = _take_state(it, C, wide, weighted)
+    code, placed, slot_pos = it
+    placed_b = placed[:, :K] != 0
+    return (
+        ids2,
+        ids2_hi,
+        s2,
+        valid2,
+        acc3,
+        w2,
+        code[:, :Mt],
+        placed_b,
+        # Same sentinel clamp as the staged step: the kernel's `big`
+        # uses lane-padded C/K widths.
+        jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1)),
+        jnp.sum(placed_b.astype(jnp.int32), axis=1),
+        jnp.sum(valid2.astype(jnp.int32), axis=1),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS + ("cand_cap",))
 def fused_frontier_step_pallas(
     ids,
     scores,
@@ -717,7 +799,7 @@ def fused_frontier_step_pallas(
     score_cap: float = 4.0,
     mode: str = "accumulate",
     initial_score: float = float(scoring.INITIAL_SCORE),
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Pallas twin of :func:`repro.kernels.ref.fused_frontier_step` —
     one jit dispatch per training step covers the whole pipeline.
@@ -727,13 +809,12 @@ def fused_frontier_step_pallas(
     payload scatter — all global gathers/sorts XLA already fuses well)
     run as jnp stages *inside this jit*; the per-PE dedup + score +
     replace + probe core runs as one ``grid=(P,)`` Pallas launch over
-    lane-padded blocks (padding: ``sk``/``prev``/``cand`` → -1, masks →
-    0 — a padded position is never first, never remote, never fresh).
-    Outputs are bit-identical to the oracle; dispatch via
+    lane-padded rows. An empty frontier (the run's final launch) and a
+    zero-capacity buffer are padded to one lane tile like any other
+    width. Outputs are bit-identical to the oracle; dispatch via
     :func:`repro.kernels.ops.fused_frontier_step_batch`. Catalog entry
     ``docs/KERNELS.md#fused_step``.
     """
-    P, C = ids.shape
     (
         active_score,
         do_replace,
@@ -743,97 +824,45 @@ def fused_frontier_step_pallas(
         rem,
         _remote,
     ) = _ref.frontier_prologue(touched_aug, part_of)
-    Mt = sk.shape[1]
-    K = cand.shape[1]
-    weighted = weights is not None
-    cw = _ref.cand_weights_of(cand, node_weights) if weighted else None
-
-    ids_p = _pad_lanes(ids.astype(jnp.int32), LANES, -1)
-    s_p = _pad_lanes(scores.astype(jnp.float32), LANES, 1.0)
-    v_p = _pad_lanes(valid.astype(jnp.int32), LANES, 0)
-    a_p = _pad_lanes(accessed.astype(jnp.int32), LANES, 0)
-    cap_p = _pad_lanes(in_capacity.astype(jnp.int32), LANES, 0)
-    sk_p = _pad_lanes(sk, LANES, -1)
-    prev_p = _pad_lanes(prev, LANES, -1)
-    rem_p = _pad_lanes(rem.astype(jnp.int32), LANES, 0)
-    c_p = _pad_lanes(cand.astype(jnp.int32), LANES, -1)
-    gates = jnp.stack(
-        [
-            active_score.astype(jnp.int32),
-            do_replace.astype(jnp.int32),
-            active_probe.astype(jnp.int32),
-        ],
-        axis=1,
-    )
-    gates = _pad_lanes(gates, LANES, 0)
-    Cp, Mp, Kp = ids_p.shape[1], sk_p.shape[1], c_p.shape[1]
-
-    def spec(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0))
-
-    operands = [ids_p, s_p, v_p, a_p, cap_p]
-    if weighted:
-        operands.append(_pad_lanes(weights.astype(jnp.float32), LANES, 1.0))
-    operands += [sk_p, prev_p, rem_p, c_p]
-    if weighted:
-        operands.append(_pad_lanes(cw.astype(jnp.float32), LANES, 0.0))
-    operands.append(gates)
-
-    out_specs = [spec(Cp)] * (5 if weighted else 4) + [
-        spec(Mp),
-        spec(Kp),
-        spec(Cp),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-    if weighted:
-        out_shape.append(jax.ShapeDtypeStruct((P, Cp), jnp.float32))
-    out_shape += [
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Kp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-
-    outs = pl.pallas_call(
-        _make_frontier_kernel(
-            float(increment),
-            float(decay),
-            float(threshold),
-            float(score_cap),
-            mode,
-            float(initial_score),
-            weighted,
+    cw = _ref.cand_weights_of(cand, node_weights) if weights is not None else None
+    (
+        ids2,
+        _,
+        s2,
+        valid2,
+        acc3,
+        w2,
+        code,
+        placed,
+        slot_pos,
+        n_place,
+        n_valid,
+    ) = _frontier_core(
+        ids,
+        None,
+        scores,
+        valid,
+        accessed,
+        in_capacity,
+        weights,
+        sk,
+        None,
+        prev,
+        None,
+        rem,
+        cand,
+        None,
+        cw,
+        _gate_rows(active_score, do_replace, active_probe),
+        constants=_constants(
+            increment, decay, threshold, score_cap, mode, initial_score
         ),
-        grid=(P,),
-        in_specs=[spec(x.shape[1]) for x in operands],
-        out_specs=out_specs,
-        out_shape=out_shape,
         interpret=interpret,
-    )(*operands)
-
-    if weighted:
-        ids2, s2, v2, acc3, w2, code, placed, slot_pos = outs
-        w_out = w2[:, :C]
-    else:
-        ids2, s2, v2, acc3, code, placed, slot_pos = outs
-        w_out = None
-    ids2 = ids2[:, :C]
-    valid2 = v2[:, :C] != 0
-    placed_b = placed[:, :K] != 0
-    code = code[:, :Mt]
-    # Same sentinel clamp as fused_step_pallas: the kernel's `big` uses
-    # lane-padded C/K widths.
-    slot_pos = jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1))
-    n_place = jnp.sum(placed_b.astype(jnp.int32), axis=1)
-    n_valid = jnp.sum(valid2.astype(jnp.int32), axis=1)
+    )
     cand_next, packed, counters, payload2 = _ref.frontier_pack(
         sk,
         code,
-        placed_b,
+        placed,
         slot_pos,
         n_place,
         n_valid,
@@ -845,10 +874,10 @@ def fused_frontier_step_pallas(
     )
     return (
         ids2,
-        s2[:, :C],
+        s2,
         valid2,
-        acc3[:, :C] != 0,
-        w_out,
+        acc3,
+        w2,
         payload2,
         cand_next,
         packed,
@@ -857,18 +886,7 @@ def fused_frontier_step_pallas(
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "cand_cap",
-        "id_base",
-        "increment",
-        "decay",
-        "threshold",
-        "score_cap",
-        "mode",
-        "initial_score",
-        "interpret",
-    ),
+    jax.jit, static_argnames=_STATICS + ("cand_cap", "id_base")
 )
 def fused_frontier_step_wide_pallas(
     ids,
@@ -895,7 +913,7 @@ def fused_frontier_step_wide_pallas(
     score_cap: float = 4.0,
     mode: str = "accumulate",
     initial_score: float = float(scoring.INITIAL_SCORE),
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Pallas twin of :func:`repro.kernels.ref.fused_frontier_step_wide`
     — the single-launch device step over ``(hi, lo)`` word-pair ids.
@@ -909,7 +927,6 @@ def fused_frontier_step_wide_pallas(
     bit-identical to the wide oracle; dispatch via
     :func:`repro.kernels.ops.fused_frontier_step_wide_batch`.
     """
-    P, C = ids.shape
     (
         active_score,
         do_replace,
@@ -921,102 +938,45 @@ def fused_frontier_step_wide_pallas(
         rem,
         _remote,
     ) = _ref.frontier_prologue_wide(touched_aug, part_of, id_base=id_base)
-    Mt = sk_lo.shape[1]
-    K = cand.shape[1]
-    weighted = weights is not None
     cw = (
         _ref.cand_weights_of_wide(cand, cand_hi, node_weights, id_base=id_base)
-        if weighted
+        if weights is not None
         else None
     )
-
-    ids_p = _pad_lanes(ids.astype(jnp.int32), LANES, -1)
-    idshi_p = _pad_lanes(ids_hi.astype(jnp.int32), LANES, -1)
-    s_p = _pad_lanes(scores.astype(jnp.float32), LANES, 1.0)
-    v_p = _pad_lanes(valid.astype(jnp.int32), LANES, 0)
-    a_p = _pad_lanes(accessed.astype(jnp.int32), LANES, 0)
-    cap_p = _pad_lanes(in_capacity.astype(jnp.int32), LANES, 0)
-    sk_p = _pad_lanes(sk_lo, LANES, -1)
-    skhi_p = _pad_lanes(sk_hi, LANES, -1)
-    prev_p = _pad_lanes(prev_lo, LANES, -1)
-    prevhi_p = _pad_lanes(prev_hi, LANES, -1)
-    rem_p = _pad_lanes(rem.astype(jnp.int32), LANES, 0)
-    c_p = _pad_lanes(cand.astype(jnp.int32), LANES, -1)
-    chi_p = _pad_lanes(cand_hi.astype(jnp.int32), LANES, -1)
-    gates = jnp.stack(
-        [
-            active_score.astype(jnp.int32),
-            do_replace.astype(jnp.int32),
-            active_probe.astype(jnp.int32),
-        ],
-        axis=1,
-    )
-    gates = _pad_lanes(gates, LANES, 0)
-    Cp, Mp, Kp = ids_p.shape[1], sk_p.shape[1], c_p.shape[1]
-
-    def spec(width):
-        return pl.BlockSpec((1, width), lambda i: (i, 0))
-
-    operands = [ids_p, idshi_p, s_p, v_p, a_p, cap_p]
-    if weighted:
-        operands.append(_pad_lanes(weights.astype(jnp.float32), LANES, 1.0))
-    operands += [sk_p, skhi_p, prev_p, prevhi_p, rem_p, c_p, chi_p]
-    if weighted:
-        operands.append(_pad_lanes(cw.astype(jnp.float32), LANES, 0.0))
-    operands.append(gates)
-
-    out_specs = [spec(Cp)] * (6 if weighted else 5) + [
-        spec(Mp),
-        spec(Kp),
-        spec(Cp),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-    if weighted:
-        out_shape.append(jax.ShapeDtypeStruct((P, Cp), jnp.float32))
-    out_shape += [
-        jax.ShapeDtypeStruct((P, Mp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Kp), jnp.int32),
-        jax.ShapeDtypeStruct((P, Cp), jnp.int32),
-    ]
-
-    outs = pl.pallas_call(
-        _make_frontier_kernel(
-            float(increment),
-            float(decay),
-            float(threshold),
-            float(score_cap),
-            mode,
-            float(initial_score),
-            weighted,
-            wide=True,
+    (
+        ids2,
+        ids2_hi,
+        s2,
+        valid2,
+        acc3,
+        w2,
+        code,
+        placed,
+        slot_pos,
+        n_place,
+        n_valid,
+    ) = _frontier_core(
+        ids,
+        ids_hi,
+        scores,
+        valid,
+        accessed,
+        in_capacity,
+        weights,
+        sk_lo,
+        sk_hi,
+        prev_lo,
+        prev_hi,
+        rem,
+        cand,
+        cand_hi,
+        cw,
+        _gate_rows(active_score, do_replace, active_probe),
+        constants=_constants(
+            increment, decay, threshold, score_cap, mode, initial_score
         ),
-        grid=(P,),
-        in_specs=[spec(x.shape[1]) for x in operands],
-        out_specs=out_specs,
-        out_shape=out_shape,
         interpret=interpret,
-    )(*operands)
-
-    if weighted:
-        ids2, ids2_hi2, s2, v2, acc3, w2, code, placed, slot_pos = outs
-        w_out = w2[:, :C]
-    else:
-        ids2, ids2_hi2, s2, v2, acc3, code, placed, slot_pos = outs
-        w_out = None
-    ids2 = ids2[:, :C]
-    ids2_hi2 = ids2_hi2[:, :C]
-    valid2 = v2[:, :C] != 0
-    placed_b = placed[:, :K] != 0
-    code = code[:, :Mt]
-    slot_pos = jnp.minimum(slot_pos[:, :C], jnp.int32(C + K + 1))
-    n_place = jnp.sum(placed_b.astype(jnp.int32), axis=1)
-    n_valid = jnp.sum(valid2.astype(jnp.int32), axis=1)
+    )
     (
         cand_next_lo,
         cand_next_hi,
@@ -1027,12 +987,12 @@ def fused_frontier_step_wide_pallas(
         sk_lo,
         sk_hi,
         code,
-        placed_b,
+        placed,
         slot_pos,
         n_place,
         n_valid,
         ids2,
-        ids2_hi2,
+        ids2_hi,
         payload,
         table,
         loc,
@@ -1041,11 +1001,11 @@ def fused_frontier_step_wide_pallas(
     )
     return (
         ids2,
-        ids2_hi2,
-        s2[:, :C],
+        ids2_hi,
+        s2,
         valid2,
-        acc3[:, :C] != 0,
-        w_out,
+        acc3,
+        w2,
         payload2,
         cand_next_lo,
         cand_next_hi,

@@ -39,7 +39,7 @@ def _make_kernel(k: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_mean(
-    table: jax.Array, indices: jax.Array, *, interpret: bool = True
+    table: jax.Array, indices: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """table (N, F), indices (B, K) -> (B, F) mean of gathered rows."""
     n, f = table.shape

@@ -35,7 +35,7 @@ def _row_index_map(i, j, idx_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows(
-    table: jax.Array, indices: jax.Array, *, interpret: bool = True
+    table: jax.Array, indices: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """table (N, F), indices (M,) int32 -> (M, F).
 
@@ -66,19 +66,21 @@ def gather_rows(
 
 
 def _batch_row_index_map(p, i, j, idx_ref):
-    return p, idx_ref[p, i], j
+    return p, idx_ref[p, i], 0, j
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_batch(
-    tables: jax.Array, indices: jax.Array, *, interpret: bool = True
+    tables: jax.Array, indices: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """tables (P, N, F), indices (P, M) int32 -> (P, M, F).
 
     Multi-PE variant for the vectorized runtime: every trainer PE's
     buffer payload is one leading-axis slice of ``tables`` and its fetch
     list one row of ``indices``; the grid gains a leading PE dimension
-    and the scalar-prefetched index map picks (PE, row) per step.
+    and the scalar-prefetched index map picks (PE, row) per step. Rows
+    ride as ``(P, N, 1, F)`` so each ``(1, F_TILE)`` block spans the
+    array's own last-but-one dim, as Mosaic's block rule requires.
     """
     P, n, f = tables.shape
     m = indices.shape[1]
@@ -92,14 +94,16 @@ def gather_rows_batch(
         num_scalar_prefetch=1,
         grid=(P, m, fp // F_TILE),
         in_specs=[
-            pl.BlockSpec((1, 1, F_TILE), _batch_row_index_map),
+            pl.BlockSpec((None, None, 1, F_TILE), _batch_row_index_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, F_TILE), lambda p, i, j, idx_ref: (p, i, j)),
+        out_specs=pl.BlockSpec(
+            (None, None, 1, F_TILE), lambda p, i, j, idx_ref: (p, i, 0, j)
+        ),
     )
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, m, fp), tables.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, m, 1, fp), tables.dtype),
         interpret=interpret,
-    )(indices.astype(jnp.int32), tables_p)
-    return out[:, :, :f]
+    )(indices.astype(jnp.int32), tables_p[:, :, None, :])
+    return out[:, :, 0, :f]

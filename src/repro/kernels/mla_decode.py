@@ -96,7 +96,7 @@ def mla_flash_decode(
     pos: jax.Array,              # scalar int32 — current length (inclusive)
     *,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns the latent context (B, H, r) = softmax(scores) @ cache_c."""
     b, h, r = q_lat.shape

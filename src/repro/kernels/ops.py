@@ -1,10 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (CPU container); pass False on real TPU.
-Every op has a pure-jnp oracle in :mod:`repro.kernels.ref` and an
-allclose sweep in ``tests/test_kernels.py``. This module owns the
-int64 / degenerate-shape fallback routing — callers never need to
-check id ranges themselves. Kernel catalog: ``docs/KERNELS.md``.
+Whether a kernel runs compiled or interpreted follows the platform
+(:func:`interpret_mode`): Mosaic-compiled on a TPU, the Pallas
+interpreter everywhere else. Every op has a pure-jnp oracle in
+:mod:`repro.kernels.ref` and an allclose sweep in
+``tests/test_kernels.py``. This module owns the int64 routing —
+callers never need to check id ranges themselves. Kernel catalog:
+``docs/KERNELS.md``.
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ from .score_update import score_update_batch as _score_update_batch
 from .segment_sum import segment_sum_equal as _segment_sum_equal
 
 __all__ = [
+    "interpret_mode",
     "gather_rows",
     "gather_rows_batch",
     "gather_mean",
@@ -123,6 +126,27 @@ def join_ids(hi, lo):
     )
 
 
+def interpret_mode() -> bool:
+    """True where Pallas kernels run in the interpreter: on every
+    backend but the TPU, whose Mosaic compiler the kernels target. The
+    one place the choice is made; no caller passes it."""
+    return jax.default_backend() != "tpu"
+
+
+def _fused_launch(backend, pallas_fn, oracle_fn):
+    """The fused-launch callable for ``backend``, counted in telemetry by
+    the path it runs: ``device.launch.compiled``, ``.interpreted`` (the
+    Pallas kernel, per :func:`interpret_mode`) or ``.oracle`` (jnp)."""
+    if backend != "pallas":
+        telemetry.count("device.launch.oracle")
+        return oracle_fn
+    interpret = interpret_mode()
+    telemetry.count(
+        "device.launch.interpreted" if interpret else "device.launch.compiled"
+    )
+    return functools.partial(pallas_fn, interpret=interpret)
+
+
 _FUSED_STATICS = (
     "increment",
     "decay",
@@ -196,7 +220,6 @@ def fused_step_batch(
     mode: str = "accumulate",
     initial_score: float = 1.0,
     backend: str = "jnp",
-    interpret: bool = True,
 ):
     """Fused per-minibatch hot path: score -> replace -> probe, one launch.
 
@@ -212,8 +235,10 @@ def fused_step_batch(
 
     ``backend="jnp"`` (default) runs the jit'd oracle
     :func:`repro.kernels.ref.fused_step`; ``backend="pallas"`` runs the
-    Pallas kernel (``kernels/fused_step.py``; ``interpret=True`` on
-    CPU). The device math is int32: int64 inputs with ids beyond the
+    Pallas kernel (``kernels/fused_step.py``, interpreted off the TPU).
+    Each launch is counted in telemetry by the path it took
+    (``device.launch.compiled`` / ``.interpreted`` / ``.oracle``). The
+    device math is int32: int64 inputs with ids beyond the
     narrow bound (:func:`int32_id_eligible`) are split into ``(hi, lo)``
     word planes and routed through the wide twin on *either* backend —
     same outputs either way, ``ids`` rejoined to int64 on host. Ground
@@ -266,33 +291,12 @@ def fused_step_batch(
             do_replace,
             active_probe,
             backend=backend,
-            interpret=interpret,
             **constants,
         )
         ids2 = join_ids(np.asarray(out[1]), np.asarray(out[0]))
         return (ids2,) + tuple(out[2:])
-    if backend == "pallas" and ids.shape[1] == 0:
-        # Zero-capacity cluster: the oracle's static early return handles
-        # C == 0; the Pallas grid would reduce over empty lane blocks.
-        backend = "jnp"
-    if backend == "pallas":
-        return _fused_step_pallas(
-            ids,
-            scores,
-            valid,
-            accessed,
-            in_capacity,
-            weights,
-            queries,
-            cand,
-            cand_weights,
-            active_score,
-            do_replace,
-            active_probe,
-            interpret=interpret,
-            **constants,
-        )
-    return _fused_step_ref(
+    fn = _fused_launch(backend, _fused_step_pallas, _fused_step_ref)
+    return fn(
         ids,
         scores,
         valid,
@@ -334,7 +338,6 @@ def fused_step_wide_batch(
     mode: str = "accumulate",
     initial_score: float = 1.0,
     backend: str = "jnp",
-    interpret: bool = True,
 ):
     """Wide-id twin of :func:`fused_step_batch`: every id operand is an
     ``(hi, lo)`` int32 word-pair plane (:func:`split_ids`), covering
@@ -351,12 +354,8 @@ def fused_step_wide_batch(
         mode=mode,
         initial_score=float(initial_score),
     )
-    if backend == "pallas" and ids.shape[1] == 0:
-        backend = "jnp"
-    fn = (
-        functools.partial(_fused_step_wide_pallas, interpret=interpret)
-        if backend == "pallas"
-        else _fused_step_wide_ref
+    fn = _fused_launch(
+        backend, _fused_step_wide_pallas, _fused_step_wide_ref
     )
     return fn(
         ids,
@@ -402,7 +401,6 @@ def fused_frontier_step_batch(
     mode: str = "accumulate",
     initial_score: float = 1.0,
     backend: str = "jnp",
-    interpret: bool = True,
 ):
     """Single-launch device step: dedup → score → replace → probe →
     gather, one dispatch per minibatch.
@@ -421,10 +419,9 @@ def fused_frontier_step_batch(
     readback cadence, ``counters``) ever crosses back to host.
     ``backend="jnp"`` runs the jit'd oracle
     :func:`repro.kernels.ref.fused_frontier_step`; ``backend="pallas"``
-    the Pallas megakernel, falling back to the oracle — identical
-    outputs — for the degenerate shapes the grid cannot express
-    (zero-capacity buffers, the final launch's empty frontier).
-    Catalog entry ``docs/KERNELS.md#fused_step``.
+    the Pallas megakernel on every shape, zero-capacity buffers and the
+    final launch's empty frontier included. Catalog entry
+    ``docs/KERNELS.md#fused_step``.
     """
     if backend not in ("jnp", "pallas"):
         raise ValueError(f"backend must be 'jnp' or 'pallas', got {backend!r}")
@@ -437,14 +434,8 @@ def fused_frontier_step_batch(
         mode=mode,
         initial_score=float(initial_score),
     )
-    if backend == "pallas" and (
-        ids.shape[1] == 0 or touched_aug.shape[1] <= 1
-    ):
-        backend = "jnp"
-    fn = (
-        functools.partial(_fused_frontier_step_pallas, interpret=interpret)
-        if backend == "pallas"
-        else _fused_frontier_ref
+    fn = _fused_launch(
+        backend, _fused_frontier_step_pallas, _fused_frontier_ref
     )
     return fn(
         ids,
@@ -491,7 +482,6 @@ def fused_frontier_step_wide_batch(
     mode: str = "accumulate",
     initial_score: float = 1.0,
     backend: str = "jnp",
-    interpret: bool = True,
 ):
     """Wide-id twin of :func:`fused_frontier_step_batch`.
 
@@ -515,14 +505,8 @@ def fused_frontier_step_wide_batch(
         mode=mode,
         initial_score=float(initial_score),
     )
-    if backend == "pallas" and (
-        ids.shape[1] == 0 or touched_aug.shape[1] <= 1
-    ):
-        backend = "jnp"
-    fn = (
-        functools.partial(_fused_frontier_step_wide_pallas, interpret=interpret)
-        if backend == "pallas"
-        else _fused_frontier_wide_ref
+    fn = _fused_launch(
+        backend, _fused_frontier_step_wide_pallas, _fused_frontier_wide_ref
     )
     return fn(
         ids,
@@ -545,7 +529,7 @@ def fused_frontier_step_wide_batch(
 
 
 @telemetry.profiled("frontier_unique_batch")
-def frontier_unique_batch(sorted_keys, is_remote, *, interpret: bool = True):
+def frontier_unique_batch(sorted_keys, is_remote):
     """Fused frontier dedup; accepts int32 **or** int64 row-sorted keys.
 
     The narrow Pallas kernel runs in int32; keys beyond the narrow bound
@@ -574,40 +558,42 @@ def frontier_unique_batch(sorted_keys, is_remote, *, interpret: bool = True):
             # Numeric int64 order == lexicographic (hi, lo) order, so
             # the row-sorted invariant carries over plane-wise.
             return _frontier_unique_batch_wide(
-                lo, hi, is_remote, interpret=interpret
+                lo, hi, is_remote, interpret=interpret_mode()
             )
         sorted_keys = keys.astype(np.int32, copy=False)
-    return _frontier_unique_batch(sorted_keys, is_remote, interpret=interpret)
+    return _frontier_unique_batch(
+        sorted_keys, is_remote, interpret=interpret_mode()
+    )
 
 
 @telemetry.profiled("gather_rows")
-def gather_rows(table, indices, *, interpret: bool = True):
-    return _gather_rows(table, indices, interpret=interpret)
+def gather_rows(table, indices):
+    return _gather_rows(table, indices, interpret=interpret_mode())
 
 
 @telemetry.profiled("gather_mean")
-def gather_mean(table, indices, *, interpret: bool = True):
-    return _gather_mean(table, indices, interpret=interpret)
+def gather_mean(table, indices):
+    return _gather_mean(table, indices, interpret=interpret_mode())
 
 
 @telemetry.profiled("segment_sum_equal")
-def segment_sum_equal(data, k: int, *, interpret: bool = True):
-    return _segment_sum_equal(data, k, interpret=interpret)
+def segment_sum_equal(data, k: int):
+    return _segment_sum_equal(data, k, interpret=interpret_mode())
 
 
 @telemetry.profiled("score_update")
-def score_update(scores, accessed, *, interpret: bool = True):
-    return _score_update(scores, accessed, interpret=interpret)
+def score_update(scores, accessed):
+    return _score_update(scores, accessed, interpret=interpret_mode())
 
 
 @telemetry.profiled("gather_rows_batch")
-def gather_rows_batch(tables, indices, *, interpret: bool = True):
-    return _gather_rows_batch(tables, indices, interpret=interpret)
+def gather_rows_batch(tables, indices):
+    return _gather_rows_batch(tables, indices, interpret=interpret_mode())
 
 
 @telemetry.profiled("score_update_batch")
-def score_update_batch(scores, accessed, *, interpret: bool = True):
-    return _score_update_batch(scores, accessed, interpret=interpret)
+def score_update_batch(scores, accessed):
+    return _score_update_batch(scores, accessed, interpret=interpret_mode())
 
 
 @telemetry.profiled("score_policy_update_batch")
@@ -621,7 +607,6 @@ def score_policy_update_batch(
     threshold: float = 0.95,
     mode: str = "accumulate",
     score_cap: float = 4.0,
-    interpret: bool = True,
 ):
     return _score_policy_update_batch(
         scores,
@@ -632,13 +617,13 @@ def score_policy_update_batch(
         threshold=threshold,
         mode=mode,
         score_cap=score_cap,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
 
 
 @telemetry.profiled("mla_flash_decode")
-def mla_flash_decode(q_lat, q_rope, cache_c, cache_kr, pos, *, scale=None,
-                     interpret: bool = True):
+def mla_flash_decode(q_lat, q_rope, cache_c, cache_kr, pos, *, scale=None):
     return _mla_flash_decode(
-        q_lat, q_rope, cache_c, cache_kr, pos, scale=scale, interpret=interpret
+        q_lat, q_rope, cache_c, cache_kr, pos, scale=scale,
+        interpret=interpret_mode(),
     )

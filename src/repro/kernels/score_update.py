@@ -34,6 +34,18 @@ SUBLANES = 8
 TILE_ROWS = 64  # (64, 128) f32 tile = 32 KiB VMEM
 
 
+def _stale_lanes(new, threshold):
+    """Per-lane stale counts of one tile as a ``(1, 128)`` row."""
+    return jnp.sum(
+        (new < jnp.float32(threshold)).astype(jnp.int32), axis=0, keepdims=True
+    )
+
+
+#: Per-tile partial stale counts: one ``(1, 128)`` row of lane sums per
+#: tile (Mosaic needs a count block whose last two dims are the array's).
+_STALE_SPEC = pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))
+
+
 def _score_kernel(scores_ref, accessed_ref, out_ref, stale_ref):
     s = scores_ref[...]
     a = accessed_ref[...] != 0
@@ -43,14 +55,12 @@ def _score_kernel(scores_ref, accessed_ref, out_ref, stale_ref):
         s * jnp.float32(scoring.DECAY_FACTOR),
     )
     out_ref[...] = new
-    stale_ref[0, 0] = jnp.sum(
-        (new < jnp.float32(scoring.STALE_THRESHOLD)).astype(jnp.int32)
-    )
+    stale_ref[...] = _stale_lanes(new, scoring.STALE_THRESHOLD)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def score_update(
-    scores: jax.Array, accessed: jax.Array, *, interpret: bool = True
+    scores: jax.Array, accessed: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array]:
     """scores (N,) f32, accessed (N,) bool -> (new_scores (N,), stale_count).
 
@@ -75,11 +85,11 @@ def score_update(
         ],
         out_specs=[
             pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _STALE_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(s2, a2)
@@ -90,7 +100,7 @@ def score_update(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def score_update_batch(
-    scores: jax.Array, accessed: jax.Array, *, interpret: bool = True
+    scores: jax.Array, accessed: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array]:
     """Multi-PE scoring round: scores (P, N) f32, accessed (P, N) bool
     -> (new_scores (P, N), stale_count (P,)).
@@ -122,16 +132,16 @@ def score_update_batch(
         ],
         out_specs=[
             pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _STALE_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(s2, a2)
     new_scores = new.reshape(P, -1)[:, :n]
-    return new_scores, jnp.sum(stale_partial.reshape(P, tiles_per_pe), axis=1)
+    return new_scores, jnp.sum(stale_partial.reshape(P, -1), axis=1)
 
 
 # --------------------------------------------------------------------- #
@@ -167,9 +177,7 @@ def _make_policy_kernel(increment, decay, threshold, score_cap, mode, weighted):
                 mode=mode,
             )
             out_ref[...] = new
-            stale_ref[0, 0] = jnp.sum(
-                (new < jnp.float32(threshold)).astype(jnp.int32)
-            )
+            stale_ref[...] = _stale_lanes(new, threshold)
 
     else:
 
@@ -184,9 +192,7 @@ def _make_policy_kernel(increment, decay, threshold, score_cap, mode, weighted):
                 mode=mode,
             )
             out_ref[...] = new
-            stale_ref[0, 0] = jnp.sum(
-                (new < jnp.float32(threshold)).astype(jnp.int32)
-            )
+            stale_ref[...] = _stale_lanes(new, threshold)
 
     return kernel
 
@@ -243,15 +249,15 @@ def _score_policy_jit(
         ),
         grid=(tiles,),
         in_specs=[block] * len(operands),
-        out_specs=[block, pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        out_specs=[block, _STALE_SPEC],
         out_shape=[
             jax.ShapeDtypeStruct((tiles * TILE_ROWS, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
     new_scores = new.reshape(P, -1)[:, :n]
-    return new_scores, jnp.sum(stale_partial.reshape(P, tiles_per_pe), axis=1)
+    return new_scores, jnp.sum(stale_partial.reshape(P, -1), axis=1)
 
 
 def score_policy_update_batch(
@@ -264,7 +270,7 @@ def score_policy_update_batch(
     threshold: float = float(scoring.STALE_THRESHOLD),
     mode: str = "accumulate",
     score_cap: float = 4.0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Policy-zoo scoring round: scores (P, N) f32, accessed (P, N) bool
     [, weights (P, N) f32] -> (new_scores (P, N), stale_count (P,)).

@@ -39,7 +39,7 @@ def _make_kernel(k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def segment_sum_equal(
-    data: jax.Array, k: int, *, interpret: bool = True
+    data: jax.Array, k: int, *, interpret: bool
 ) -> jax.Array:
     """data (S*k, F) sorted by segment, k rows per segment -> (S, F)."""
     e, f = data.shape

@@ -32,24 +32,6 @@ from .common import dtype_of, init_dense
 from .config import ModelConfig
 from .mlp import init_mlp, mlp_forward
 
-import inspect
-from functools import partial as _partial
-
-try:  # jax >= 0.6: top-level API
-    _sm = jax.shard_map
-except AttributeError:  # older jax: experimental API
-    from jax.experimental.shard_map import shard_map as _sm
-
-# The replication-check kwarg was renamed check_rep -> check_vma
-# independently of the API promotion; pick by signature, not version.
-if "check_vma" in inspect.signature(_sm).parameters:
-    _shard_map = _partial(_sm, check_vma=False)
-elif "check_rep" in inspect.signature(_sm).parameters:
-    _shard_map = _partial(_sm, check_rep=False)
-else:
-    _shard_map = _sm
-
-
 def init_moe(cfg: ModelConfig, key) -> dict:
     dt = dtype_of(cfg)
     m = cfg.moe
@@ -379,7 +361,7 @@ def moe_forward_ep(
         aux_spec = P(ba)
         local_body = _moe_local_body
     axis_names = tuple(mesh.axis_names)
-    body = _shard_map(
+    body = jax.shard_map(
         lambda r, wg, wu, wd, xb: local_body(cfg, axis_names, r, wg, wu, wd, xb),
         mesh=mesh,
         in_specs=(
@@ -390,6 +372,7 @@ def moe_forward_ep(
             bspec,                               # tokens
         ),
         out_specs=(bspec, aux_spec),
+        check_vma=False,
     )
     y, aux_vec = body(
         params["router"], params["w_gate"], params["w_up"], params["w_down"], x

@@ -262,11 +262,11 @@ class PrefetchEngine:
             return
         weights = self.weights if self.policy.use_weights else None
         if self.use_kernels:
-            from ..kernels.score_update import score_policy_update_batch
+            from ..kernels import ops
 
             kc = self.policy.kernel_constants()
             kc.pop("initial_score")  # scoring pass never places slots
-            new, _ = score_policy_update_batch(
+            new, _ = ops.score_policy_update_batch(
                 self.scores, self.accessed, weights, **kc
             )
             new = np.asarray(new, dtype=np.float32)
@@ -445,7 +445,6 @@ class DeviceEngine:
         self,
         engine: PrefetchEngine,
         backend: str = "jnp",
-        interpret: bool = True,
         part_of: np.ndarray | None = None,
         id_base: int | None = None,
     ):
@@ -477,7 +476,6 @@ class DeviceEngine:
         self._jnp = jnp
         self.engine = engine
         self.backend = backend
-        self.interpret = interpret
         self.policy = engine.policy
         self.stats = engine.stats  # shared — trainer.engine.stats stays live
         self.capacity = engine.capacity
@@ -664,7 +662,6 @@ class DeviceEngine:
                 cw,
                 *gates,
                 backend=self.backend,
-                interpret=self.interpret,
                 **self.policy.kernel_constants(),
             )
         else:
@@ -692,7 +689,6 @@ class DeviceEngine:
                 cw,
                 *gates,
                 backend=self.backend,
-                interpret=self.interpret,
                 **self.policy.kernel_constants(),
             )
         tel.end(_launch_sp)
@@ -890,7 +886,6 @@ class DeviceEngine:
                 cand_cap=self.cand_cap,
                 id_base=self.id_base,
                 backend=self.backend,
-                interpret=self.interpret,
                 **self.policy.kernel_constants(),
             )
         else:
@@ -921,7 +916,6 @@ class DeviceEngine:
                 loc,
                 cand_cap=self.cand_cap,
                 backend=self.backend,
-                interpret=self.interpret,
                 **self.policy.kernel_constants(),
             )
         tel.end(_launch_sp)

@@ -184,7 +184,12 @@ class FeatureStore:
         the feature payload never crosses the host boundary. Cached
         until :meth:`poke` invalidates it. Requires the flat row count
         to be int32-addressable — the same bound the device engine
-        already enforces on node ids."""
+        already enforces on node ids.
+
+        The view lives on the default device, beside the engine's state,
+        even when the store's own table is sharded: a launch with one
+        sharded operand would be partitioned over every device, and
+        Mosaic kernels cannot be partitioned automatically."""
         if self._dev_view is None:
             import jax.numpy as jnp
 
@@ -196,7 +201,7 @@ class FeatureStore:
                     "device view indexes rows as int32"
                 )
             self._dev_view = (
-                self._dev if self._dev is not None else jnp.asarray(self._flat),
+                jnp.asarray(self._flat),
                 jnp.asarray(self._loc.astype(np.int32)),
             )
         return self._dev_view
