@@ -47,6 +47,74 @@ from ..sim import StepComm
 from .stage import DecisionStage, FetchStage, FusedFetchStage, SampleStage
 
 
+def _train_step(trainer, minibatches) -> float:
+    """One synchronous SGD step over every trainer's minibatch; returns
+    the mean of their losses.
+
+    Per trainer, in order: its features are gathered on the host
+    (``train.gather``), uploaded (``train.upload``), and ``sage_grads``
+    runs on them with the loss read back (``train.grads``). Then the
+    gradients are summed in trainer order, averaged and applied
+    (``train.update``); all of it inside one ``train`` span. While a
+    session is active the upload and the update wait for their arrays,
+    so each span holds its own transfer or device work; with telemetry
+    off nothing waits, and the upload is the one ``sage_grads`` would
+    make of the host arrays.
+    """
+    from ..gnn.sage import sage_grads
+
+    P = trainer.parts.num_parts
+    tree_map = jax.tree_util.tree_map
+    loss_acc = 0.0
+    grads = []
+    with tel.span("train"):
+        for mb in minibatches:
+            with tel.span("train.gather") as sp:
+                host = (*trainer._features_of(mb), mb.labels)
+                sp.nbytes = sum(x.nbytes for x in host[:3])
+            with tel.span("train.upload") as sp:
+                batch = jax.device_put(host)
+                if tel.enabled():
+                    jax.block_until_ready(batch)
+                    sp.nbytes = sum(x.nbytes for x in batch)
+                    tel.count("device.h2d_bytes", sp.nbytes)
+            with tel.span("train.grads"):
+                loss, g = sage_grads(trainer.params, *batch)
+                # The call holds its inputs until it has run; dropping them
+                # here keeps one trainer's inputs on the device at a time,
+                # as the implicit upload of host arrays did.
+                del batch
+                loss_acc += float(loss) / P
+                tel.count("device.d2h_bytes", loss.nbytes)
+            grads.append(g)
+        with tel.span("train.update"):
+            acc = grads[0]
+            for g in grads[1:]:
+                acc = tree_map(lambda a, b: a + b, acc, g)
+            mean = tree_map(lambda g: g / P, acc)
+            trainer.params = tree_map(
+                lambda prm, g: prm - trainer.lr * g, trainer.params, mean
+            )
+            if tel.enabled():
+                jax.block_until_ready(trainer.params)
+    return loss_acc
+
+
+def _final_accuracy(trainer) -> float:
+    """Accuracy of the trained weights on one minibatch of the first
+    (up to) 512 training nodes; 0.0 when the run trains no model."""
+    if not trainer.train_model:
+        return 0.0
+    from ..gnn.sage import sage_accuracy
+
+    batch = trainer.graph.train_nodes[: min(512, len(trainer.graph.train_nodes))]
+    minibatch = trainer.sampler.sample(batch, trainer.rng)
+    x_seed, x_n1, x_n2 = trainer._features_of(minibatch)
+    return float(
+        sage_accuracy(trainer.params, x_seed, x_n1, x_n2, minibatch.labels)
+    )
+
+
 def run_vectorized(trainer) -> "RunResult":  # noqa: F821 — see lazy import
     """Execute ``trainer``'s experiment on the vectorized runtime.
 
@@ -83,7 +151,6 @@ def run_vectorized(trainer) -> "RunResult":  # noqa: F821 — see lazy import
                 stacklevel=2,
             )
     # Deferred: repro.gnn.train imports the engine from this package.
-    from ..gnn.sage import sage_accuracy, sage_grads
     from ..gnn.train import RunResult, TrainerLog
 
     P = trainer.parts.num_parts
@@ -186,46 +253,11 @@ def run_vectorized(trainer) -> "RunResult":  # noqa: F821 — see lazy import
                 )
 
             if trainer.train_model:
-                _train_sp = tel.begin("train", plane="train")
-                grads_acc = None
-                loss_acc = 0.0
-                for p in range(P):
-                    x_seed, x_n1, x_n2 = trainer._features_of(minibatches[p])
-                    loss, grads = sage_grads(
-                        trainer.params, x_seed, x_n1, x_n2, minibatches[p].labels
-                    )
-                    loss_acc += float(loss) / P
-                    grads_acc = (
-                        grads
-                        if grads_acc is None
-                        else jax.tree_util.tree_map(
-                            lambda a, b: a + b, grads_acc, grads
-                        )
-                    )
-                if grads_acc is not None:
-                    grads_mean = jax.tree_util.tree_map(
-                        lambda g: g / P, grads_acc
-                    )
-                    trainer.params = jax.tree_util.tree_map(
-                        lambda prm, g: prm - trainer.lr * g,
-                        trainer.params,
-                        grads_mean,
-                    )
-                    losses.append(loss_acc)
-                tel.end(_train_sp)
+                losses.append(_train_step(trainer, minibatches))
             tel.end(_step_sp)
         epoch_times.append(epoch_time)
 
-    accuracy = 0.0
-    if trainer.train_model:
-        batch = trainer.graph.train_nodes[
-            : min(512, len(trainer.graph.train_nodes))
-        ]
-        minibatch = trainer.sampler.sample(batch, trainer.rng)
-        x_seed, x_n1, x_n2 = trainer._features_of(minibatch)
-        accuracy = float(
-            sage_accuracy(trainer.params, x_seed, x_n1, x_n2, minibatch.labels)
-        )
+    accuracy = _final_accuracy(trainer)
 
     trace = None
     if recorder is not None:
@@ -315,7 +347,6 @@ def _run_device_cadence(
     this mode — only :meth:`DeviceEngine.sync_to_engine`'s array state
     and the shared stats are written back.
     """
-    from ..gnn.sage import sage_accuracy, sage_grads
     from ..gnn.train import RunResult, TrainerLog
 
     jnp = dev._jnp
@@ -437,45 +468,14 @@ def _run_device_cadence(
             flush()
 
         if trainer.train_model:
-            grads_acc = None
-            loss_acc = 0.0
-            for p in range(P):
-                x_seed, x_n1, x_n2 = trainer._features_of(minibatches[p])
-                loss, grads = sage_grads(
-                    trainer.params, x_seed, x_n1, x_n2, minibatches[p].labels
-                )
-                loss_acc += float(loss) / P
-                grads_acc = (
-                    grads
-                    if grads_acc is None
-                    else jax.tree_util.tree_map(
-                        lambda a, b: a + b, grads_acc, grads
-                    )
-                )
-            if grads_acc is not None:
-                grads_mean = jax.tree_util.tree_map(lambda g: g / P, grads_acc)
-                trainer.params = jax.tree_util.tree_map(
-                    lambda prm, g: prm - trainer.lr * g,
-                    trainer.params,
-                    grads_mean,
-                )
-                losses.append(loss_acc)
+            losses.append(_train_step(trainer, minibatches))
 
         minibatches = nxt_mb
         tel.end(_step_sp)
 
     flush()
 
-    accuracy = 0.0
-    if trainer.train_model:
-        batch = trainer.graph.train_nodes[
-            : min(512, len(trainer.graph.train_nodes))
-        ]
-        minibatch = trainer.sampler.sample(batch, trainer.rng)
-        x_seed, x_n1, x_n2 = trainer._features_of(minibatch)
-        accuracy = float(
-            sage_accuracy(trainer.params, x_seed, x_n1, x_n2, minibatch.labels)
-        )
+    accuracy = _final_accuracy(trainer)
 
     dev.sync_to_engine()
     return RunResult(
@@ -527,7 +527,6 @@ def run_device(trainer) -> "RunResult":  # noqa: F821 — see lazy import
     (:func:`_run_device_cadence`; per-step id streams are not
     materialized — gated by :func:`_check_cadence_eligible`).
     """
-    from ..gnn.sage import sage_accuracy, sage_grads
     from ..gnn.train import RunResult, TrainerLog
     from .engine import DeviceEngine
 
@@ -668,29 +667,7 @@ def run_device(trainer) -> "RunResult":  # noqa: F821 — see lazy import
             )
 
         if trainer.train_model:
-            grads_acc = None
-            loss_acc = 0.0
-            for p in range(P):
-                x_seed, x_n1, x_n2 = trainer._features_of(minibatches[p])
-                loss, grads = sage_grads(
-                    trainer.params, x_seed, x_n1, x_n2, minibatches[p].labels
-                )
-                loss_acc += float(loss) / P
-                grads_acc = (
-                    grads
-                    if grads_acc is None
-                    else jax.tree_util.tree_map(
-                        lambda a, b: a + b, grads_acc, grads
-                    )
-                )
-            if grads_acc is not None:
-                grads_mean = jax.tree_util.tree_map(lambda g: g / P, grads_acc)
-                trainer.params = jax.tree_util.tree_map(
-                    lambda prm, g: prm - trainer.lr * g,
-                    trainer.params,
-                    grads_mean,
-                )
-                losses.append(loss_acc)
+            losses.append(_train_step(trainer, minibatches))
 
         minibatches = nxt_mb
         probe = next_probe
@@ -700,16 +677,7 @@ def run_device(trainer) -> "RunResult":  # noqa: F821 — see lazy import
             remote, n_remote = nxt_remote, nxt_n_remote
         tel.end(_step_sp)
 
-    accuracy = 0.0
-    if trainer.train_model:
-        batch = trainer.graph.train_nodes[
-            : min(512, len(trainer.graph.train_nodes))
-        ]
-        minibatch = trainer.sampler.sample(batch, trainer.rng)
-        x_seed, x_n1, x_n2 = trainer._features_of(minibatch)
-        accuracy = float(
-            sage_accuracy(trainer.params, x_seed, x_n1, x_n2, minibatch.labels)
-        )
+    accuracy = _final_accuracy(trainer)
 
     dev.sync_to_engine()
     trace = None

@@ -67,8 +67,6 @@ __all__ = [
     "begin",
     "end",
     "count",
-    "gauge",
-    "observe",
     "spanned",
     "profiled",
 ]
@@ -155,20 +153,6 @@ def count(name: str, value=1, shape=None) -> None:
     if s is None:
         return
     s.registry.counter(name, shape=shape).add(value)
-
-
-def gauge(name: str, value) -> None:
-    s = _SESSION
-    if s is None:
-        return
-    s.registry.gauge(name).set(value)
-
-
-def observe(name: str, value) -> None:
-    s = _SESSION
-    if s is None:
-        return
-    s.registry.histogram(name).observe(value)
 
 
 def spanned(name: str, plane: str = ""):

@@ -10,15 +10,15 @@ hook is one global load and a ``None`` check.
 
 Kernel profiling (``profile_call``) wraps a dispatcher call with
 ``jax.block_until_ready`` timing — the block is what makes the number
-mean "kernel finished", not "dispatch returned" — and optionally a
-``jax.profiler.TraceAnnotation`` so the span also shows up in a real
-XLA profiler trace when one is being captured.
+mean "kernel finished", not "dispatch returned" — inside a
+``jax.profiler.TraceAnnotation`` named ``kernel.<name>``, as its
+counters are, so the call shows up in a profiler capture beside the
+tracer's spans (which annotate under their own names).
 """
 
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 
 from .registry import MetricsRegistry
 from .spans import SpanTracer
@@ -31,11 +31,9 @@ class TelemetrySession:
         self,
         label: str = "run",
         profile_kernels: bool = True,
-        annotate: bool = False,
     ):
         self.label = label
         self.profile_kernels = profile_kernels
-        self.annotate = annotate
         self.registry = MetricsRegistry()
         self.tracer = SpanTracer()
         self.meta: dict = {}
@@ -45,13 +43,8 @@ class TelemetrySession:
         """Call ``fn`` with block-until-ready timing under ``name``."""
         import jax
 
-        annotation = (
-            jax.profiler.TraceAnnotation(f"repro.{name}")
-            if self.annotate
-            else nullcontext()
-        )
         t0 = time.perf_counter()
-        with annotation:
+        with jax.profiler.TraceAnnotation(f"kernel.{name}"):
             out = fn(*args, **kwargs)
             jax.block_until_ready(out)
         dt = time.perf_counter() - t0

@@ -10,6 +10,11 @@ Each finished span records its *inclusive* duration and the summed
 duration of its direct children (``child_s``); the difference is its
 *exclusive* (self) time, which is what per-plane breakdowns sum so
 that a plane's seconds are never double-counted against its callees'.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+opened and closed with it, so a ``jax.profiler`` capture holds the
+program's spans on the host threads, on the device ops' clock. A tracer
+exists only inside a session, so with telemetry off nothing is built.
 """
 
 from __future__ import annotations
@@ -32,6 +37,7 @@ class Span:
         "nbytes",
         "child_s",
         "_tracer",
+        "_annotation",
     )
 
     def __init__(self, tracer: "SpanTracer", name: str, pe: int, plane: str, nbytes: int):
@@ -44,6 +50,7 @@ class Span:
         self.t1 = 0.0
         self.depth = 0
         self.child_s = 0.0
+        self._annotation = None
 
     @property
     def duration(self) -> float:
@@ -74,10 +81,19 @@ class Span:
         }
 
 
+def _close(span: Span) -> None:
+    if span._annotation is not None:
+        span._annotation.__exit__(None, None, None)
+        span._annotation = None
+
+
 class SpanTracer:
     """Collects finished spans; per-track stacks give nesting depth."""
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
         self.spans: list[Span] = []
         self._stacks: dict[int, list[Span]] = {}
         self.origin = time.perf_counter()
@@ -87,6 +103,8 @@ class SpanTracer:
 
     # -- context-manager protocol driven by Span ----------------------- #
     def _enter(self, span: Span) -> None:
+        span._annotation = self._annotate(span.name)
+        span._annotation.__enter__()
         stack = self._stacks.setdefault(span.pe, [])
         span.depth = len(stack)
         stack.append(span)
@@ -100,13 +118,15 @@ class SpanTracer:
         elif stack and span in stack:
             # Mis-nested begin/end (an exception unwound past an open
             # begin token): drop everything above it rather than corrupt
-            # the depth accounting for the rest of the run.
+            # the depth accounting for the rest of the run, and close
+            # their annotations inside this span's.
             while stack[-1] is not span:
-                stack.pop()
+                _close(stack.pop())
             stack.pop()
         if stack:
             stack[-1].child_s += span.duration
         self.spans.append(span)
+        _close(span)
 
     # -- explicit begin/end (for loop bodies where `with` would force a
     #    large re-indent); telemetry-off callers get None tokens ------- #

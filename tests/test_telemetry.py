@@ -11,7 +11,9 @@ the plane observes, it never perturbs.
 
 from __future__ import annotations
 
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -55,6 +57,35 @@ COMMON = dict(
     variant="fixed", epochs=2, batch_size=16, fanouts=(3, 5),
     train_model=False, buffer_frac=0.25, interval=4, trace=True,
 )
+#: COMMON with the GraphSAGE step on: the ``train`` span and its parts.
+TRAIN = dict(COMMON, train_model=True, epochs=1, hidden_dim=16)
+#: The two vectorized runtimes: staged (host) and device-resident.
+RUNTIMES = {"staged": {}, "device": {"device": "jnp"}}
+TRAIN_PARTS = ("train.gather", "train.upload", "train.grads", "train.update")
+
+
+def capture(logdir, fn):
+    """Run ``fn`` under a ``jax.profiler`` capture; returns its result and
+    the capture's host events as ``(line, name, start_ns, end_ns)``."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [
+        (line.name, e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    ]
+    return out, events
 
 
 # ---------------------------------------------------------------------- #
@@ -191,8 +222,13 @@ class TestHelpers:
         assert tel.begin("x") is None
         tel.end(None)
         tel.count("c", 5)
-        tel.gauge("g", 1.0)
-        tel.observe("h", 2.0)
+
+    def test_removed_helpers_and_knob_are_gone(self):
+        # Spans always annotate while a session is active: no knob.
+        assert not hasattr(tel, "gauge") and not hasattr(tel, "observe")
+        assert "gauge" not in tel.__all__ and "observe" not in tel.__all__
+        with pytest.raises(TypeError):
+            TelemetrySession(annotate=True)
 
     def test_activate_twice_raises(self):
         with tel.active(TelemetrySession()):
@@ -316,6 +352,21 @@ class TestContract:
         assert session.meta["num_pes"] == 4
         assert not tel.enabled()  # deactivated after the run
 
+    def test_off_capture_holds_no_program_spans(self, parts, tmp_path):
+        """A profiler capture of a telemetry-off training run holds none
+        of the program's spans, and the run matches a telemetry-on one:
+        same digest, same losses to the bit."""
+        t_on = DistributedTrainer(parts, telemetry=True, **TRAIN)
+        r_on = t_on.run()
+        names = {s.name for s in t_on.last_telemetry.tracer.spans}
+        assert set(TRAIN_PARTS) <= names
+        t_off = DistributedTrainer(parts, **TRAIN)
+        r_off, events = capture(tmp_path, t_off.run)
+        assert events  # the capture itself worked
+        assert not names & {e[1] for e in events}
+        assert t_off.last_trace.exact_digest() == t_on.last_trace.exact_digest()
+        assert r_off.losses == r_on.losses and r_off.losses
+
     def test_int64_fallback_counts_and_warns_once(self, parts, monkeypatch):
         from repro.kernels import ops
 
@@ -340,6 +391,110 @@ class TestContract:
             t.telemetry = TelemetrySession()
             t.run()
         assert t.last_telemetry.registry["device.fallback_int64"].total == 1.0
+
+
+# ---------------------------------------------------------------------- #
+# the training step from inside: train.* spans, upload bytes, profiler
+# ---------------------------------------------------------------------- #
+class TestTrainingStep:
+    @pytest.fixture(scope="class", params=sorted(RUNTIMES))
+    def traced(self, request, parts):
+        t = DistributedTrainer(
+            parts, telemetry=True, **RUNTIMES[request.param], **TRAIN
+        )
+        result = t.run()
+        return t, result, t.last_telemetry.tracer.spans
+
+    @staticmethod
+    def _children(spans, parent):
+        return [
+            s for s in spans
+            if s.pe == parent.pe and s.depth == parent.depth + 1
+            and parent.t0 <= s.t0 and s.t1 <= parent.t1
+        ]
+
+    def test_parts_are_children_of_train(self, traced):
+        t, result, spans = traced
+        trains = [s for s in spans if s.name == "train"]
+        steps, P = len(result.losses), t.parts.num_parts
+        assert len(trains) == steps > 0
+        for tr in trains:
+            kids = [s.name for s in self._children(spans, tr)]
+            assert kids == ["train.gather", "train.upload", "train.grads"] * P + [
+                "train.update"
+            ]
+        for s in spans:
+            if s.name in TRAIN_PARTS:
+                assert s.pe == -1 and s.plane == "train"
+
+    def test_self_times_sum_to_train(self, traced):
+        _, _, spans = traced
+        for tr in (s for s in spans if s.name == "train"):
+            kids = self._children(spans, tr)
+            total = tr.self_s + sum(k.self_s for k in kids)
+            assert total == pytest.approx(tr.duration, abs=1e-9)
+
+    def test_upload_bytes_are_the_uploaded_arrays(self, parts, monkeypatch):
+        import jax
+
+        t = DistributedTrainer(parts, telemetry=True, **TRAIN)
+        gathered = []
+        features_of = t._features_of
+
+        def spy(mb):
+            out = features_of(mb)
+            gathered.append((out, mb.labels))
+            return out
+
+        monkeypatch.setattr(t, "_features_of", spy)
+        result = t.run()
+        P = t.parts.num_parts
+        steps = len(result.losses)
+        # every trainer step's, then the final accuracy's (not uploaded)
+        assert len(gathered) == P * steps + 1
+        gathered = gathered[:-1]
+        label_bytes = jax.dtypes.canonicalize_dtype(np.int64).itemsize
+        expect = sum(
+            sum(x.nbytes for x in xs) + len(labels) * label_bytes
+            for xs, labels in gathered
+        )
+        reg = t.last_telemetry.registry
+        assert reg["device.h2d_bytes"].total == expect
+        uploads = [s for s in t.last_telemetry.tracer.spans if s.name == "train.upload"]
+        assert sum(s.nbytes for s in uploads) == expect
+        gathers = [s for s in t.last_telemetry.tracer.spans if s.name == "train.gather"]
+        assert sum(s.nbytes for s in gathers) == sum(
+            sum(x.nbytes for x in xs) for xs, _ in gathered
+        )
+        # one float32 loss read back per trainer step
+        assert reg["device.d2h_bytes"].total == 4 * P * steps
+
+    @pytest.mark.parametrize("runtime", sorted(RUNTIMES))
+    def test_profiler_capture_holds_every_span(self, parts, tmp_path, runtime):
+        t = DistributedTrainer(parts, telemetry=True, **RUNTIMES[runtime], **TRAIN)
+        _, events = capture(tmp_path, t.run)
+        spans = t.last_telemetry.tracer.spans
+        by_name: dict[str, list] = {}
+        for line, name, a, b in events:
+            by_name.setdefault(name, []).append((line, a, b))
+        for name, row in t.last_telemetry.tracer.by_name().items():
+            assert len(by_name.get(name, [])) == row["count"], name
+        trains = by_name["train"]
+        for name in TRAIN_PARTS:
+            for line, a, b in by_name[name]:
+                assert any(
+                    ln == line and a0 <= a and b <= b0 for ln, a0, b0 in trains
+                ), name
+        if runtime == "device":
+            # profiled kernel calls annotate as their counters are named
+            kernels = {n for n in by_name if n.startswith("kernel.")}
+            calls = {
+                n[: -len(".calls")]
+                for n in t.last_telemetry.registry.names()
+                if n.startswith("kernel.") and n.endswith(".calls")
+            }
+            assert kernels == calls and calls
+        assert len(spans) == sum(len(by_name[n]) for n in {s.name for s in spans})
 
 
 # ---------------------------------------------------------------------- #
