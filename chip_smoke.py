@@ -121,6 +121,7 @@ def phase_a() -> None:
     import numpy as np
 
     from repro.gnn.sage import sage_loss
+    from repro.gnn.train import index_blocks
     from repro.runtime.stage import SampleStage
 
     t0 = time.perf_counter()
@@ -152,10 +153,12 @@ def phase_a() -> None:
     loss_cpu = 0.0
     with jax.default_device(cpu):
         params_cpu = jax.device_put(params0, cpu)
+        table_cpu = jax.device_put(parts.graph.features, cpu)
         loss_fn = jax.jit(sage_loss)
         for mb in minibatches:
-            x_seed, x_n1, x_n2 = tr._features_of(mb)
-            loss_cpu += float(loss_fn(params_cpu, x_seed, x_n1, x_n2, mb.labels)) / P
+            *ids, labels = jax.device_put(index_blocks(mb), cpu)
+            rows = tr.feature_rows(table_cpu, None, ids)
+            loss_cpu += float(loss_fn(params_cpu, *rows, labels)) / P
     rel = abs(losses[0] - loss_cpu) / abs(loss_cpu)
     log(
         f"phase A: first-step loss chip={losses[0]!r} cpu={loss_cpu!r} "

@@ -9,6 +9,10 @@ full scale). The forward consumes the dense padded blocks produced by
     x_n1   : (B, f1, F)      sampled neighbors of seeds
     x_n2   : (B, f1, f2, F)  sampled neighbors of those neighbors
 
+Each block may also be given as :class:`Rows`: the block's node ids into
+a device-resident feature table, gathered inside the program that reads
+them, so only the ids cross the host boundary.
+
 Aggregation is a mean over the fanout axis — the same segment-mean that
 ``kernels/segment_sum`` implements as a Pallas TPU kernel for the
 CSR-ordered (variable-degree) full-graph case.
@@ -20,6 +24,55 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+
+@jax.tree_util.register_pytree_node_class
+class Rows:
+    """The feature block of an id block, left in a device table until a
+    program reads it: ``table[loc[idx]]``, or ``table[idx]`` without
+    ``loc``, of shape ``idx.shape + (F,)``; a table wider than ``F``
+    (padded to whole lanes) is cut to its first ``F`` columns. Indexing
+    selects ids as it would the block (``rows[:h]`` holds the first
+    ``h`` nodes' rows). :meth:`fanout_mean` reduces the block without
+    laying it out in its own shape first."""
+
+    def __init__(self, table, loc, idx, width: int):
+        self.table, self.loc, self.idx, self.width = table, loc, idx, width
+
+    def tree_flatten(self):
+        return (self.table, self.loc, self.idx), self.width
+
+    @classmethod
+    def tree_unflatten(cls, width, children):
+        return cls(*children, width)
+
+    def __getitem__(self, key) -> "Rows":
+        return Rows(self.table, self.loc, self.idx[key], self.width)
+
+    def _gather(self, idx) -> jax.Array:
+        rows = idx if self.loc is None else self.loc[idx]
+        return self.table[rows]
+
+    def read(self) -> jax.Array:
+        return self._gather(self.idx)[..., : self.width]
+
+    def fanout_mean(self) -> jax.Array:
+        """Mean over the last id axis, gathered fanout-major: the
+        ``(f, n, F)`` rows keep the tiles of the gather's own output, so
+        nothing is laid out again before the sum."""
+        f = self.idx.shape[-1]
+        idx = jnp.moveaxis(self.idx, -1, 0).reshape(f, -1)
+        mean = jnp.mean(self._gather(idx), axis=0)[:, : self.width]
+        return mean.reshape(self.idx.shape[:-1] + (self.width,))
+
+
+def _block(x) -> jax.Array:
+    return x.read() if isinstance(x, Rows) else x
+
+
+def _fanout_mean(x) -> jax.Array:
+    """Mean over a block's fanout axis, the last before the features."""
+    return x.fanout_mean() if isinstance(x, Rows) else jnp.mean(x, axis=-2)
 
 
 class SageLayer(NamedTuple):
@@ -67,13 +120,13 @@ def sage_forward(
     x_n1: jax.Array,
     x_n2: jax.Array,
 ) -> jax.Array:
-    """Returns logits (B, num_classes)."""
+    """Returns logits (B, num_classes); any block may be :class:`Rows`."""
     # Layer 1 applied to every node that layer 2 will read.
     h_n1 = jax.nn.relu(
-        _sage_combine(params.layer1, x_n1, jnp.mean(x_n2, axis=2))
+        _sage_combine(params.layer1, _block(x_n1), _fanout_mean(x_n2))
     )  # (B, f1, H)
     h_seed = jax.nn.relu(
-        _sage_combine(params.layer1, x_seed, jnp.mean(x_n1, axis=1))
+        _sage_combine(params.layer1, _block(x_seed), _fanout_mean(x_n1))
     )  # (B, H)
     # Layer 2 on seeds.
     logits = _sage_combine(params.layer2, h_seed, jnp.mean(h_n1, axis=1))

@@ -72,7 +72,24 @@ from ..graph.generate import (
 from ..graph.partition import Partitioned
 from ..graph.sampler import MiniBatch, NeighborSampler, SamplerPlane, unique_remote
 from ..runtime.engine import PrefetchEngine
-from .sage import init_sage, sage_accuracy, sage_grads
+from .sage import Rows, init_sage, sage_grads
+
+#: Lane width of a TPU vector register: a float32 table whose rows fill
+#: whole lanes is laid out row-major, so a row gather needs no relayout.
+LANES = 128
+
+
+def index_blocks(minibatch: MiniBatch) -> tuple[np.ndarray, ...]:
+    """What a training step uploads of a minibatch: its seed, first-hop
+    and second-hop ids as int32 blocks of shapes ``(B,)``, ``(B, f1)``
+    and ``(B, f1, f2)`` (local rows of the graph), and its labels."""
+    b, f1 = minibatch.layer_nbrs[0].shape
+    return (
+        minibatch.seeds.astype(np.int32),
+        minibatch.layer_nbrs[0].astype(np.int32),
+        minibatch.layer_nbrs[1].reshape(b, f1, -1).astype(np.int32),
+        minibatch.labels.astype(np.int32),
+    )
 
 
 @dataclass
@@ -385,6 +402,7 @@ class DistributedTrainer:
         # changing any exact stream (the conformance contract of
         # tests/test_trace_golden.py).
         self.feature_store = None
+        self._feature_table = None  # see feature_table()
         if feature_store:
             from ..store import FeatureStore
 
@@ -528,26 +546,45 @@ class DistributedTrainer:
             idx = np.concatenate([idx, perm[: self.batch_size - len(idx)]])
         return t[idx]
 
-    def _features_of(self, minibatch: MiniBatch):
-        if self.feature_store is not None:
-            # The training step consumes actual store rows (bit-identical
-            # to graph.features rows — the store only re-homes them).
-            # Minibatch ids are local; the store is keyed by global id.
-            store = self.feature_store
-            base = np.int64(self.graph.id_base)
-            x_seed = store.gather(minibatch.seeds + base)
-            x_n1 = store.gather(minibatch.layer_nbrs[0] + base)
-            b, f1 = minibatch.layer_nbrs[0].shape
-            x_n2 = store.gather(minibatch.layer_nbrs[1] + base).reshape(
-                b, f1, -1, store.feature_dim
+    def feature_table(self):
+        """``(table, loc)``: the feature table training reads, on the
+        device, and the int32 map from a node's local id to its row
+        (None where the id is the row).
+
+        Uploaded once, at the first training step, and kept for every
+        later ``run()``: ``graph.features`` with its rows padded to whole
+        lanes, so that the TPU lays the table out row by row and a row
+        gather reads it in place; or with a feature store the store's own
+        :meth:`FeatureStore.device_view`, asked for at every step so that
+        a :meth:`FeatureStore.poke` reaches training. An upload counts
+        ``device.h2d_bytes`` and ``train.table_uploads``.
+        """
+        store = self.feature_store
+        if store is not None:
+            fresh = not store.has_device_view
+            table, loc = store.device_view()
+        else:
+            fresh = self._feature_table is None
+            if fresh:
+                features = self.graph.features
+                pad = -features.shape[1] % LANES
+                self._feature_table = jax.device_put(
+                    np.pad(features, ((0, 0), (0, pad)))
+                )
+            table, loc = self._feature_table, None
+        if fresh:
+            tel.count(
+                "device.h2d_bytes",
+                table.nbytes + (0 if loc is None else loc.nbytes),
             )
-            return x_seed, x_n1, x_n2
-        f = self.graph.features
-        x_seed = f[minibatch.seeds]
-        x_n1 = f[minibatch.layer_nbrs[0]]
-        b, f1 = minibatch.layer_nbrs[0].shape
-        x_n2 = f[minibatch.layer_nbrs[1]].reshape(b, f1, -1, f.shape[1])
-        return x_seed, x_n1, x_n2
+            tel.count("train.table_uploads", 1)
+        return table, loc
+
+    def feature_rows(self, table, loc, ids) -> tuple[Rows, ...]:
+        """:class:`Rows` of the uploaded seed, first- and second-hop id
+        blocks (the first three of :func:`index_blocks`) in ``table``."""
+        width = self.graph.features.shape[1]
+        return tuple(Rows(table, loc, idx, width) for idx in ids)
 
     # ------------------------------------------------------------------ #
     def make_time_engine(self):
@@ -773,9 +810,11 @@ class DistributedTrainer:
                     stall_ticks.append(ctrl.step_stall())
 
                     if self.train_model:
-                        x_seed, x_n1, x_n2 = self._features_of(minibatch)
+                        *ids, labels = jax.device_put(index_blocks(minibatch))
                         loss, grads = sage_grads(
-                            self.params, x_seed, x_n1, x_n2, minibatch.labels
+                            self.params,
+                            *self.feature_rows(*self.feature_table(), ids),
+                            labels,
                         )
                         loss_acc += float(loss) / P
                         grads_acc = (
@@ -878,14 +917,9 @@ class DistributedTrainer:
                 tel.end(_step_sp)
             epoch_times.append(epoch_time)
 
-        accuracy = 0.0
-        if self.train_model:
-            batch = self.graph.train_nodes[: min(512, len(self.graph.train_nodes))]
-            minibatch = self.sampler.sample(batch, self.rng)
-            x_seed, x_n1, x_n2 = self._features_of(minibatch)
-            accuracy = float(
-                sage_accuracy(self.params, x_seed, x_n1, x_n2, minibatch.labels)
-            )
+        from ..runtime.driver import _final_accuracy
+
+        accuracy = _final_accuracy(self)
 
         trace = None
         if recorder is not None:

@@ -51,26 +51,29 @@ def _train_step(trainer, minibatches) -> float:
     """One synchronous SGD step over every trainer's minibatch; returns
     the mean of their losses.
 
-    Per trainer, in order: its features are gathered on the host
-    (``train.gather``), uploaded (``train.upload``), and ``sage_grads``
-    runs on them with the loss read back (``train.grads``). Then the
-    gradients are summed in trainer order, averaged and applied
-    (``train.update``); all of it inside one ``train`` span. While a
-    session is active the upload and the update wait for their arrays,
-    so each span holds its own transfer or device work; with telemetry
-    off nothing waits, and the upload is the one ``sage_grads`` would
-    make of the host arrays.
+    The features stay in the trainer's device table
+    (:meth:`DistributedTrainer.feature_table`, uploaded at the first
+    step). Per trainer, in order: its int32 id blocks are built on the
+    host (``train.gather``), uploaded with its labels (``train.upload``),
+    and ``sage_grads`` gathers their rows from the table and runs on
+    them, with the loss read back (``train.grads``). Then the gradients
+    are summed in trainer order, averaged and applied (``train.update``);
+    all of it inside one ``train`` span. While a session is active the
+    upload and the update wait for their arrays, so each span holds its
+    own transfer or device work; with telemetry off nothing waits.
     """
-    from ..gnn.sage import sage_grads
+    from ..gnn import sage
+    from ..gnn.train import index_blocks
 
     P = trainer.parts.num_parts
     tree_map = jax.tree_util.tree_map
     loss_acc = 0.0
     grads = []
     with tel.span("train"):
+        table, loc = trainer.feature_table()
         for mb in minibatches:
             with tel.span("train.gather") as sp:
-                host = (*trainer._features_of(mb), mb.labels)
+                host = index_blocks(mb)
                 sp.nbytes = sum(x.nbytes for x in host[:3])
             with tel.span("train.upload") as sp:
                 batch = jax.device_put(host)
@@ -79,11 +82,15 @@ def _train_step(trainer, minibatches) -> float:
                     sp.nbytes = sum(x.nbytes for x in batch)
                     tel.count("device.h2d_bytes", sp.nbytes)
             with tel.span("train.grads"):
-                loss, g = sage_grads(trainer.params, *batch)
+                *ids, labels = batch
+                loss, g = sage.sage_grads(
+                    trainer.params,
+                    *trainer.feature_rows(table, loc, ids),
+                    labels,
+                )
                 # The call holds its inputs until it has run; dropping them
-                # here keeps one trainer's inputs on the device at a time,
-                # as the implicit upload of host arrays did.
-                del batch
+                # here keeps one trainer's inputs on the device at a time.
+                del batch, ids, labels
                 loss_acc += float(loss) / P
                 tel.count("device.d2h_bytes", loss.nbytes)
             grads.append(g)
@@ -106,13 +113,13 @@ def _final_accuracy(trainer) -> float:
     if not trainer.train_model:
         return 0.0
     from ..gnn.sage import sage_accuracy
+    from ..gnn.train import index_blocks
 
     batch = trainer.graph.train_nodes[: min(512, len(trainer.graph.train_nodes))]
     minibatch = trainer.sampler.sample(batch, trainer.rng)
-    x_seed, x_n1, x_n2 = trainer._features_of(minibatch)
-    return float(
-        sage_accuracy(trainer.params, x_seed, x_n1, x_n2, minibatch.labels)
-    )
+    *ids, labels = jax.device_put(index_blocks(minibatch))
+    rows = trainer.feature_rows(*trainer.feature_table(), ids)
+    return float(sage_accuracy(trainer.params, *rows, labels))
 
 
 def run_vectorized(trainer) -> "RunResult":  # noqa: F821 — see lazy import

@@ -206,6 +206,12 @@ class FeatureStore:
             )
         return self._dev_view
 
+    @property
+    def has_device_view(self) -> bool:
+        """Whether :meth:`device_view` holds its pair, so that asking for
+        it uploads nothing."""
+        return self._dev_view is not None
+
     # ------------------------------------------------------------------ #
     def _rows_of(self, ids: np.ndarray) -> np.ndarray:
         flat = ids.reshape(-1).astype(np.int64, copy=False)

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro import telemetry as tel
-from repro.gnn.train import DistributedTrainer
+from repro.gnn.train import LANES, DistributedTrainer
 from repro.graph import generate, partition_graph
 from repro.telemetry import (
     Calibration,
@@ -435,36 +435,41 @@ class TestTrainingStep:
             assert total == pytest.approx(tr.duration, abs=1e-9)
 
     def test_upload_bytes_are_the_uploaded_arrays(self, parts, monkeypatch):
-        import jax
+        import repro.gnn.train as train
 
         t = DistributedTrainer(parts, telemetry=True, **TRAIN)
-        gathered = []
-        features_of = t._features_of
+        built = []
+        index_blocks = train.index_blocks
 
         def spy(mb):
-            out = features_of(mb)
-            gathered.append((out, mb.labels))
+            out = index_blocks(mb)
+            built.append(out)
             return out
 
-        monkeypatch.setattr(t, "_features_of", spy)
+        monkeypatch.setattr(train, "index_blocks", spy)
         result = t.run()
         P = t.parts.num_parts
         steps = len(result.losses)
-        # every trainer step's, then the final accuracy's (not uploaded)
-        assert len(gathered) == P * steps + 1
-        gathered = gathered[:-1]
-        label_bytes = jax.dtypes.canonicalize_dtype(np.int64).itemsize
-        expect = sum(
-            sum(x.nbytes for x in xs) + len(labels) * label_bytes
-            for xs, labels in gathered
-        )
+        # every trainer step's, then the final accuracy's (not counted)
+        assert len(built) == P * steps + 1
+        built = built[:-1]
+        B, (f1, f2) = TRAIN["batch_size"], TRAIN["fanouts"]
+        for blocks in built:
+            assert [x.shape for x in blocks] == [(B,), (B, f1), (B, f1, f2), (B,)]
+            assert all(x.dtype == np.int32 for x in blocks)
+        per_step = sum(sum(x.nbytes for x in blocks) for blocks in built)
+        assert per_step == P * steps * (B + B * f1 + B * f1 * f2 + B) * 4
+        # the one table upload, at the first step, then ids and labels only
+        N, F = t.graph.features.shape
+        table = N * -(-F // LANES) * LANES * 4  # float32 rows padded to whole lanes
         reg = t.last_telemetry.registry
-        assert reg["device.h2d_bytes"].total == expect
+        assert reg["train.table_uploads"].total == 1
+        assert reg["device.h2d_bytes"].total == table + per_step
         uploads = [s for s in t.last_telemetry.tracer.spans if s.name == "train.upload"]
-        assert sum(s.nbytes for s in uploads) == expect
+        assert sum(s.nbytes for s in uploads) == per_step
         gathers = [s for s in t.last_telemetry.tracer.spans if s.name == "train.gather"]
         assert sum(s.nbytes for s in gathers) == sum(
-            sum(x.nbytes for x in xs) for xs, _ in gathered
+            sum(x.nbytes for x in blocks[:3]) for blocks in built
         )
         # one float32 loss read back per trainer step
         assert reg["device.d2h_bytes"].total == 4 * P * steps

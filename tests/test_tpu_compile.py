@@ -154,3 +154,26 @@ def test_score_policy_update_batch_compiles(shape, no_persistent_cache):
         shape((P, C), jnp.bool_),
         shape((P, C), jnp.float32),
     )
+
+
+def test_sage_grads_gathers_from_the_table_in_place(shape, no_persistent_cache):
+    """Training gathers its rows from the device feature table inside
+    ``sage_grads``. The trainer pads the table's rows to whole lanes
+    (``DistributedTrainer.feature_table``), so the chip lays it out row
+    by row and the program reads it where it lies: no copy of the table
+    (an unpadded F = 100 table is copied, once per block, every call)."""
+    from repro.gnn.sage import Rows, init_sage, sage_grads
+    from repro.gnn.train import LANES
+
+    width = -(-F // LANES) * LANES
+    params = jax.tree_util.tree_map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_sage(jax.random.PRNGKey(0), F, 256, 47)),
+    )
+    table = shape((N_NODES, width), jnp.float32)
+    rows = [Rows(table, None, shape(dims), F) for dims in [(32,), (32, 10), (32, 10, 25)]]
+    hlo = sage_grads.lower(params, *rows, shape((32,))).compile().as_text()
+    assert not [
+        line for line in hlo.splitlines()
+        if f"[{N_NODES},{width}]" in line and " copy(" in line
+    ]
