@@ -11,8 +11,8 @@ one JSON line of the program's readings against the reference
 (``check.py``). For each control seed it also reads, on the same
 minibatches, with the reference put in the program's place:
 
-* ``control``: the reference computed in bfloat16 (the configuration
-  states float32);
+* ``control``: the model's reference computed in bfloat16 (the
+  configuration states float32);
 * ``half_batch``: each trainer's loss over half of its seeds;
 * ``no_exchange``: trainer 0's gradient in place of the mean over
   trainers;
@@ -69,8 +69,8 @@ def main(argv=None) -> int:
         ref = harness.reference_run(job, table)
 
         def restart(**_):
-            warm = reference.train(job.w0, table, steps[:window_at], job.lr)
-            win = reference.train(job.w0, table, steps[window_at:], job.lr)
+            warm = job.model.train(job.w0, table, steps[:window_at], job.lr)
+            win = job.model.train(job.w0, table, steps[window_at:], job.lr)
             return warm[0] + win[0], warm[1], warm[2] + win[2]
 
         for kind, run in (

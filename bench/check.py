@@ -1,9 +1,10 @@
 """The comparison that decides a run's ``correct``.
 
 Training (every cell): the program, driven through
-``DistributedTrainer.run()`` from the benchmark's weights, against
-``reference.train`` from the same weights on the same minibatches,
-along every step of the warm-up and the window's first three:
+``DistributedTrainer.run()`` from the model's weights, against the
+model's plain reference (``train`` of its module under
+``bench/models``) from the same weights on the same minibatches, along
+every step of the warm-up and the window's first three:
 
 * ``loss_gap``: the largest relative gap of a step's loss over the
   warm-up's first three steps;

@@ -3,20 +3,23 @@
 A cell (an entry of ``workloads``) names a configuration and a traffic
 mix. The configuration is ``<bench>/configs/<config>.json``: the graph's
 published numbers and its twin's shape (``graphs.py`` makes it), the
-number of trainers and the model's job parameters, and the limits of
-the correctness check. The mix is ``<bench>/traffic/<traffic>.json``:
-the rest of the job's parameters (variant, controller, batch, buffer)
-and whether the job keeps a prefetch buffer (``buffer``). Every metric
-is a reader ``<bench>/metrics/<name>.py`` with a function
-``read(run) -> float | None`` over the :class:`Run` record. Nothing here
-knows a cell, a configuration, a mix or a metric by name.
+number of trainers, the model (``model``) and its job parameters, and
+the limits of the correctness check. The model is a module
+``<bench>/models/<model>.py``: its plain reference, its weights' layout
+and its work counts (``load_model``). The mix is
+``<bench>/traffic/<traffic>.json``: the rest of the job's parameters
+(variant, controller, batch, buffer) and whether the job keeps a
+prefetch buffer (``buffer``). Every metric is a reader
+``<bench>/metrics/<name>.py`` with a function ``read(run) -> float |
+None`` over the :class:`Run` record. Nothing here knows a cell, a
+configuration, a model, a mix or a metric by name.
 
 A run:
 
 1. makes the graph and its partitioning, or loads them from
    ``<bench>/.cache/graphs`` (``graphs.partitioned``);
 2. builds one ``DistributedTrainer`` from the configuration's and the
-   mix's job parameters with ``seed``, gives it the benchmark's weights,
+   mix's job parameters with ``seed``, gives it the model's weights,
    made from ``seed``, and records what its sampler hands each step;
 3. warms up with one ``run()`` of whole epochs, three steps at least:
    this compiles (or loads from the compile cache) every program the
@@ -44,7 +47,6 @@ from pathlib import Path
 import numpy as np
 
 from . import check, devtrace, graphs, reference
-from .flops import SageShapes
 
 SPEC_FILE = "BENCHMARK.json"
 #: Steps at least in the warm-up; its first are compared step by step.
@@ -68,6 +70,8 @@ class Cell:
     mix: dict
     chips: int
     bench_dir: Path
+    #: The configuration's model module (``load_model``).
+    model: object
 
 
 def load_spec(root: Path) -> dict:
@@ -84,9 +88,15 @@ def resolve_cell(root: Path, spec: dict, name: str) -> Cell:
     bench_dir = root / spec["paths"][0]
     with open(root / cfg["file"]) as f:
         config = json.load(f)
+    if "model" not in config:
+        raise ValueError(
+            f"{root / cfg['file']} names no model: give it \"model\", a module "
+            f"<model>.py under {bench_dir / 'models'}"
+        )
     with open(bench_dir / "traffic" / f"{w['traffic']}.json") as f:
         mix = json.load(f)
-    return Cell(name, config, mix, int(w["chips"]), bench_dir)
+    model = load_model(bench_dir, config["model"])
+    return Cell(name, config, mix, int(w["chips"]), bench_dir, model)
 
 
 def metric_specs(spec: dict, cell: str, trace: bool) -> list[dict]:
@@ -104,14 +114,29 @@ def metric_specs(spec: dict, cell: str, trace: bool) -> list[dict]:
     ]
 
 
-def load_reader(bench_dir: Path, name: str):
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _load_module(bench_dir: Path, kind: str, name: str):
+    """The module ``<bench>/<kind>/<name>.py``, loaded by path."""
+    path = bench_dir / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     if spec is None or not path.exists():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+        raise FileNotFoundError(f"no {kind} module {name!r} at {path}")
     mod = importlib.util.module_from_spec(spec)
+    # Registered before it runs: a dataclass looks its module up.
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: Path, name: str):
+    return _load_module(bench_dir, "metrics", name).read
+
+
+def load_model(bench_dir: Path, name: str):
+    """The model module ``<bench>/models/<name>.py``: ``shapes(config,
+    trainer_kwargs)``, ``LEAVES``, ``init_weights(seed, shapes)``,
+    ``train(w0, table, steps, lr, dtype, *, fault)``, ``grads_flops``,
+    ``grads_bytes`` and ``PROGRAM`` (``bench/README.md``)."""
+    return _load_module(bench_dir, "models", name)
 
 
 # --------------------------------------------------------------------- #
@@ -137,7 +162,7 @@ class SamplerSpy:
         run["weights"].append(self.trainer.params)
         run["t"].append(time.perf_counter())
         run["batches"].append(
-            [(mb.seeds, mb.layer_nbrs[0], mb.layer_nbrs[1], mb.labels) for mb in minibatches]
+            [(mb.seeds, list(mb.layer_nbrs), mb.labels) for mb in minibatches]
         )
 
     def sample_all(self, seed_blocks, rng, **kw):
@@ -158,7 +183,8 @@ class SamplerSpy:
 
     def steps(self, k: int, n: int | None = None) -> list[list[tuple]]:
         """The minibatches of run ``k``'s first ``n`` steps (all without
-        ``n``), ``[step][trainer]``."""
+        ``n``), ``[step][trainer]``, each as ``(seeds, hops, labels)``
+        with every hop of the sampler's ``layer_nbrs``."""
         return self.runs[k]["batches"][:n]
 
     def weights(self, k: int) -> list:
@@ -172,24 +198,23 @@ class SamplerSpy:
         return float(statistics.median(np.diff(t[1:]))) if len(t) > 2 else t[-1] - t[0]
 
 
-def _leaves(tree) -> dict:
+def _leaves(tree, names: tuple) -> dict:
     import jax
 
     return {
-        k: np.asarray(v, np.float64)
-        for k, v in zip(reference.LEAVES, jax.tree_util.tree_leaves(tree))
+        k: np.asarray(v, np.float64) for k, v in zip(names, jax.tree_util.tree_leaves(tree))
     }
 
 
-def _set_weights(trainer, w0: dict) -> None:
+def _set_weights(trainer, w0: dict, names: tuple) -> None:
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten(trainer.params)
-    new = [w0[k] for k in reference.LEAVES]
+    new = [w0[k] for k in names]
     if len(leaves) != len(new) or any(a.shape != b.shape for a, b in zip(leaves, new)):
         raise ValueError(
-            "the trainer's weights do not have the layout "
-            f"{reference.LEAVES}: {[a.shape for a in leaves]}"
+            f"the trainer's weights do not have the layout {names}: "
+            f"{[a.shape for a in leaves]}"
         )
     trainer.params = jax.tree_util.tree_unflatten(treedef, new)
 
@@ -201,7 +226,8 @@ def _set_weights(trainer, w0: dict) -> None:
 class Run:
     cell: Cell
     device_kind: str
-    shapes: SageShapes
+    #: The model's ``Shapes`` for this cell.
+    shapes: object
     trainers: int
     setup_s: float
     window_s: float
@@ -215,6 +241,11 @@ class Run:
     session: object | None = None
     #: The window's profiler trace, reduced by ``devtrace`` (traced runs).
     trace: dict | None = None
+
+    @property
+    def model(self):
+        """The configuration's model module."""
+        return self.cell.model
 
     @property
     def logs(self) -> list:
@@ -232,6 +263,14 @@ class Run:
             for s in self.session.tracer.spans
             if s.name in names or s.name.startswith(tuple(prefixes))
         ]
+        return sum(sel) if sel else None
+
+    def span_total_s(self, names=()) -> float | None:
+        """Summed inclusive time of the window's spans with one of
+        ``names``; None untraced or when no such span ran."""
+        if self.session is None:
+            return None
+        sel = [s.duration for s in self.session.tracer.spans if s.name in names]
         return sum(sel) if sel else None
 
 
@@ -269,7 +308,8 @@ class Job:
     trainer: object
     spy: SamplerSpy
     w0: dict
-    shapes: SageShapes
+    model: object
+    shapes: object
     kwargs: dict
     trainers: int
     uses_buffer: bool
@@ -289,7 +329,7 @@ class Job:
 
 
 def build(cell: Cell, seed: int, cache_dir: Path | None = None) -> Job:
-    """The cell's trainer with the benchmark's weights and the spy."""
+    """The cell's trainer with the model's weights and the spy."""
     from repro.gnn import DistributedTrainer
 
     parts, halos = graphs.partitioned(
@@ -302,18 +342,13 @@ def build(cell: Cell, seed: int, cache_dir: Path | None = None) -> Job:
     tr = DistributedTrainer(parts, seed=seed, epochs=1, **kw)
     if tr.parts.num_parts != P:
         raise ValueError(f"partitioned {tr.parts.num_parts}-way, configured {P}")
-    shapes = SageShapes(
-        batch=tr.batch_size,
-        fanouts=tuple(kw["fanouts"]),
-        feature_dim=graph.features.shape[1],
-        hidden=kw["hidden_dim"],
-        classes=graph.num_classes,
-    )
-    w0 = reference.init_weights(seed, shapes.feature_dim, shapes.hidden, shapes.classes)
-    _set_weights(tr, w0)
+    model = cell.model
+    shapes = model.shapes(cell.config, kw)
+    w0 = model.init_weights(seed, shapes)
+    _set_weights(tr, w0, model.LEAVES)
     spy = SamplerSpy(tr)
     tr.sampler_plane = spy
-    return Job(parts, halos, tr, spy, w0, shapes, kw, P, bool(cell.mix["buffer"]))
+    return Job(parts, halos, tr, spy, w0, model, shapes, kw, P, bool(cell.mix["buffer"]))
 
 
 def warm_up(job: Job):
@@ -336,17 +371,17 @@ def program_path(job: Job) -> tuple[list[float], list[dict]]:
     warm, window = job.spy.weights(0), job.spy.weights(1)
     n = min(WINDOW_STEPS, len(job.runs[1].losses))
     losses = list(job.runs[0].losses) + list(job.runs[1].losses[:n])
-    after = [_leaves(w) for w in warm[1:] + window[1 : n + 1]]
+    after = [_leaves(w, job.model.LEAVES) for w in warm[1:] + window[1 : n + 1]]
     return losses, after
 
 
 def reference_run(job: Job, table=None, **kw):
-    """``reference.train`` over the followed steps, with the feature
+    """The model's ``train`` over the followed steps, with the feature
     table on the device (``table``, or uploaded here)."""
     steps, _ = followed_steps(job)
     if table is None:
         table = reference.device_table(job.parts.graph.features)
-    return reference.train(job.w0, table, steps, job.lr, **kw)
+    return job.model.train(job.w0, table, steps, job.lr, **kw)
 
 
 def steps_unchanged(job: Job) -> int:

@@ -1,8 +1,10 @@
-"""Training step per step: self time of the ``train`` spans over the
-traced window, per step. It holds the host gather of every trainer's
-features, their upload, ``sage_grads`` and the loss read back."""
+"""Training step per step: inclusive time of the ``train`` spans over
+the traced window, per step. It holds every trainer's id blocks, their
+upload, ``sage_grads`` and the loss read back, and the weight update:
+the sum of ``feature_gather_ms``, ``upload_ms``, ``grads_ms``,
+``update_ms`` and the loop's own residue."""
 
 
 def read(run):
-    s = run.span_self_s(names=("train",))
+    s = run.span_total_s(names=("train",))
     return None if s is None else 1e3 * s / run.steps

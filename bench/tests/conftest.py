@@ -20,6 +20,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 TINY = {
     "name": "tiny-sage",
     "source": "https://ogb.stanford.edu/docs/nodeprop/#ogbn-products",
+    "model": "sage",
     "num_nodes": 2880,
     "num_edges": 36000,
     "feature_dim": 100,

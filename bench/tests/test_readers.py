@@ -8,11 +8,12 @@ from types import SimpleNamespace
 import pytest
 from conftest import ROOT
 
-from bench import devtrace, flops, harness, peaks
+from bench import devtrace, harness, peaks
+from bench.models import sage
 from repro.telemetry import TelemetrySession
 from repro.telemetry.spans import Span
 
-SHAPES = flops.SageShapes(batch=2000, fanouts=(10, 25), feature_dim=100, hidden=256, classes=47)
+SHAPES = sage.Shapes(batch=2000, fanouts=(10, 25), feature_dim=100, hidden=256, classes=47)
 KIND = "TPU v5 lite"
 
 #: One step of the staged path: (name, start, end, child seconds).
@@ -62,8 +63,8 @@ def logs():
 @pytest.fixture
 def run():
     return harness.Run(
-        cell=None, device_kind=KIND, shapes=SHAPES, trainers=4, setup_s=12.5,
-        window_s=2.0, steps=2, seeds=16000, result=SimpleNamespace(logs=logs()),
+        cell=SimpleNamespace(model=sage), device_kind=KIND, shapes=SHAPES, trainers=4,
+        setup_s=12.5, window_s=2.0, steps=2, seeds=16000, result=SimpleNamespace(logs=logs()),
         session=recorded_session(), trace=TRACE,
     )
 
@@ -96,10 +97,10 @@ def test_trace_metrics(run):
     pk = peaks.peak(KIND)
     # Busy: [0.3, 0.45] and [1.3, 1.4] s -> 0.25 s of 2 s.
     assert read("device_idle_pct", run) == pytest.approx(87.5)
-    least = max(flops.sage_grads_flops(SHAPES) / pk.flops_bf16,
-                flops.sage_grads_bytes(SHAPES) / pk.hbm_bytes_per_s)
+    least = max(sage.grads_flops(SHAPES) / pk.flops_bf16,
+                sage.grads_bytes(SHAPES) / pk.hbm_bytes_per_s)
     assert read("sage_grads_roofline", run) == pytest.approx(100 * least * 2 / 0.25)
-    mfu = 100 * flops.sage_grads_flops(SHAPES) * 4 * 2 / 2.0 / pk.flops_bf16
+    mfu = 100 * sage.grads_flops(SHAPES) * 4 * 2 / 2.0 / pk.flops_bf16
     assert read("sage_mfu", run) == pytest.approx(mfu)
 
 

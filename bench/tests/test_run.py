@@ -46,6 +46,7 @@ def test_same_seed_same_inputs(tiny_root, graph_cache):
     for job in (a, b):
         harness.warm_up(job)
     for x, y in zip(a.spy.steps(0, 3), b.spy.steps(0, 3)):
-        for u, v in zip(x, y):
-            assert all((p == q).all() for p, q in zip(u, v))
+        for (s1, h1, l1), (s2, h2, l2) in zip(x, y):
+            assert (s1 == s2).all() and (l1 == l2).all() and len(h1) == len(h2) == 2
+            assert all((p == q).all() for p, q in zip(h1, h2))
     assert a.runs[0].losses == b.runs[0].losses

@@ -58,8 +58,8 @@ def session(steps=STEP, h2d=H2D_BYTES):
 
 def make_run(sess, trace=None):
     return harness.Run(
-        cell=None, device_kind="TPU v5 lite", shapes=None, trainers=2, setup_s=1.0,
-        window_s=2.0, steps=2, seeds=8000, result=SimpleNamespace(logs=[]),
+        cell=None, device_kind="TPU v5 lite", shapes=None, trainers=2,
+        setup_s=1.0, window_s=2.0, steps=2, seeds=8000, result=SimpleNamespace(logs=[]),
         session=sess, trace=trace,
     )
 
@@ -78,10 +78,14 @@ def test_span_readers(name, per_step_ms):
 
 
 def test_parts_and_train_residue_partition_train():
+    # train_ms is the inclusive time of ``train``: its four parts and the
+    # loop's own residue (the span's self time) add up to it.
     run = make_run(session())
     parts = sum(read(n, run) for n in SPAN_METRICS)
-    assert parts + read("train_ms", run) == pytest.approx(900.0)
-    assert read("train_ms", run) == pytest.approx(10.0)
+    residue = 1e3 * run.span_self_s(names=("train",)) / run.steps
+    assert residue == pytest.approx(10.0)
+    assert parts + residue == pytest.approx(read("train_ms", run))
+    assert read("train_ms", run) == pytest.approx(900.0)
 
 
 def test_h2d_reader():
