@@ -33,8 +33,9 @@ class Rows:
     ``loc``, of shape ``idx.shape + (F,)``; a table wider than ``F``
     (padded to whole lanes) is cut to its first ``F`` columns. Indexing
     selects ids as it would the block (``rows[:h]`` holds the first
-    ``h`` nodes' rows). :meth:`fanout_mean` reduces the block without
-    laying it out in its own shape first."""
+    ``h`` nodes' rows). :meth:`fanout_mean` reduces the block and
+    :meth:`fanout_major` lays it out fanout first, each without laying
+    it out in its own shape first."""
 
     def __init__(self, table, loc, idx, width: int):
         self.table, self.loc, self.idx, self.width = table, loc, idx, width
@@ -64,6 +65,15 @@ class Rows:
         idx = jnp.moveaxis(self.idx, -1, 0).reshape(f, -1)
         mean = jnp.mean(self._gather(idx), axis=0)[:, : self.width]
         return mean.reshape(self.idx.shape[:-1] + (self.width,))
+
+    def fanout_major(self) -> jax.Array:
+        """The rows with the id axes reversed, as ``(f, n, F)``: ``f``
+        the last id axis, ``n`` the others, the first varying fastest.
+        Row ``[j, i]`` is then the ``j``-th child of the ``i``-th row of
+        the block one hop up read the same way, so every hop's rows line
+        up with the next hop's as the gather lays them out."""
+        idx = jnp.transpose(self.idx)
+        return self._gather(idx.reshape(idx.shape[0], -1))[..., : self.width]
 
 
 def _block(x) -> jax.Array:

@@ -10,7 +10,8 @@ Runs the three variants of §5 on a partitioned graph:
 
 What is *exact*: partitioning, sampling, buffer membership/scoring,
 hit/miss sets, remote fetch counts (bytes), decision streams, GNN
-training math (JAX GraphSAGE with data-parallel gradient averaging —
+training math (JAX GraphSAGE or GAT, ``model=...``, with data-parallel
+gradient averaging —
 Rudder never alters sampling or training, so accuracy is unaffected by
 the variant, as the paper states).
 
@@ -51,6 +52,7 @@ Two interchangeable execution paths produce the run (see
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Callable, NamedTuple
 
 import jax
 import numpy as np
@@ -72,24 +74,53 @@ from ..graph.generate import (
 from ..graph.partition import Partitioned
 from ..graph.sampler import MiniBatch, NeighborSampler, SamplerPlane, unique_remote
 from ..runtime.engine import PrefetchEngine
-from .sage import Rows, init_sage, sage_grads
+from . import gat, sage
+from .sage import Rows
 
 #: Lane width of a TPU vector register: a float32 table whose rows fill
 #: whole lanes is laid out row-major, so a row gather needs no relayout.
 LANES = 128
 
 
+class Model(NamedTuple):
+    """A model the trainer trains: ``init(key, feature_dim, hidden_dim,
+    num_classes, heads, num_layers)`` makes its weights; ``grads`` and
+    ``accuracy`` take ``(params, blocks, labels)``, ``blocks`` the seed
+    block and one block per hop (:func:`index_blocks`), as features or
+    :class:`Rows`."""
+
+    init: Callable
+    grads: Callable
+    accuracy: Callable
+
+
+# Each function is looked up on its module at the call, so that one
+# replaced there (as the benchmark's fault tests replace sage_grads)
+# is the one a trainer runs.
+MODELS = {
+    "sage": Model(
+        init=lambda key, f, h, c, heads, layers: sage.init_sage(key, f, h, c),
+        grads=lambda params, blocks, y: sage.sage_grads(params, *blocks, y),
+        accuracy=lambda params, blocks, y: sage.sage_accuracy(params, *blocks, y),
+    ),
+    "gat": Model(
+        init=lambda *a: gat.init_gat(*a),
+        grads=lambda *a: gat.gat_grads(*a),
+        accuracy=lambda *a: gat.gat_accuracy(*a),
+    ),
+}
+
+
 def index_blocks(minibatch: MiniBatch) -> tuple[np.ndarray, ...]:
-    """What a training step uploads of a minibatch: its seed, first-hop
-    and second-hop ids as int32 blocks of shapes ``(B,)``, ``(B, f1)``
-    and ``(B, f1, f2)`` (local rows of the graph), and its labels."""
-    b, f1 = minibatch.layer_nbrs[0].shape
-    return (
-        minibatch.seeds.astype(np.int32),
-        minibatch.layer_nbrs[0].astype(np.int32),
-        minibatch.layer_nbrs[1].reshape(b, f1, -1).astype(np.int32),
-        minibatch.labels.astype(np.int32),
-    )
+    """What a training step uploads of a minibatch: its seed ids and
+    each hop's as int32 blocks of shapes ``(B,)``, ``(B, f1)``, ...,
+    ``(B, f1, ..., fL)`` (local rows of the graph), and its labels."""
+    blocks = [minibatch.seeds.astype(np.int32)]
+    shape = blocks[0].shape
+    for nbrs in minibatch.layer_nbrs:
+        shape += nbrs.shape[-1:]
+        blocks.append(nbrs.reshape(shape).astype(np.int32))
+    return (*blocks, minibatch.labels.astype(np.int32))
 
 
 @dataclass
@@ -288,6 +319,8 @@ class DistributedTrainer:
         device: object = False,
         readback_every: int = 1,
         telemetry: object = False,
+        model: str = "sage",
+        heads: int | None = None,
     ):
         if runtime not in ("vectorized", "legacy"):
             raise ValueError(
@@ -306,6 +339,24 @@ class DistributedTrainer:
             )
         if device and runtime == "legacy":
             raise ValueError("device mode requires runtime='vectorized'")
+        if model not in MODELS:
+            raise ValueError(f"model must be one of {sorted(MODELS)}, got {model!r}")
+        if model == "sage":
+            if heads is not None:
+                raise ValueError("heads is a setting of model='gat'")
+            if train_model and len(fanouts) != 2:
+                raise ValueError(
+                    f"GraphSAGE trains 2 layers on 2 hops, got fanouts {tuple(fanouts)}"
+                )
+        else:
+            heads = 4 if heads is None else heads
+            if not isinstance(heads, int) or heads < 1:
+                raise ValueError(f"heads must be an int >= 1, got {heads!r}")
+            if len(fanouts) < 2:
+                raise ValueError(
+                    f"GAT trains one layer per hop, at least 2, got fanouts {tuple(fanouts)}"
+                )
+        self.model = MODELS[model]
         self.device = device or False
         # K-step readback cadence for sweep runs: with device mode on and
         # K > 1, the driver pulls only a stacked (K, P, 4) counter block
@@ -525,11 +576,13 @@ class DistributedTrainer:
 
         if train_model:
             key = jax.random.PRNGKey(seed)
-            self.params = init_sage(
+            self.params = self.model.init(
                 key,
                 self.graph.features.shape[1],
                 hidden_dim,
                 self.graph.num_classes,
+                heads,
+                len(fanouts),
             )
 
     # ------------------------------------------------------------------ #
@@ -581,8 +634,8 @@ class DistributedTrainer:
         return table, loc
 
     def feature_rows(self, table, loc, ids) -> tuple[Rows, ...]:
-        """:class:`Rows` of the uploaded seed, first- and second-hop id
-        blocks (the first three of :func:`index_blocks`) in ``table``."""
+        """:class:`Rows` in ``table`` of the uploaded seed block and of
+        each hop's (all of :func:`index_blocks` but the labels)."""
         width = self.graph.features.shape[1]
         return tuple(Rows(table, loc, idx, width) for idx in ids)
 
@@ -811,9 +864,9 @@ class DistributedTrainer:
 
                     if self.train_model:
                         *ids, labels = jax.device_put(index_blocks(minibatch))
-                        loss, grads = sage_grads(
+                        loss, grads = self.model.grads(
                             self.params,
-                            *self.feature_rows(*self.feature_table(), ids),
+                            self.feature_rows(*self.feature_table(), ids),
                             labels,
                         )
                         loss_acc += float(loss) / P

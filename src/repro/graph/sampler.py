@@ -1,7 +1,8 @@
 """Neighbor sampling (GraphSAGE-style fanout sampling).
 
-Matches the paper's setup: 2-layer GraphSAGE with fanout {25, 10} —
-every seed samples up to 10 neighbors, each of which samples up to 25.
+Matches the paper's setup: 2-layer GraphSAGE with fanout (10, 25) —
+every seed samples up to 10 neighbors, each of which samples up to 25;
+any number of hops can be drawn (GAT trains on three).
 Sampling is with replacement when a node has fewer neighbors than the
 fanout (isolated nodes fall back to self-loops), which yields dense
 ``(batch, fanout)`` index blocks that JAX consumes without masking.
@@ -55,7 +56,7 @@ def _gather_neighbors(
 class NeighborSampler:
     def __init__(self, graph: Graph, fanouts: tuple[int, ...] = (10, 25)):
         """``fanouts[0]`` applies to the seeds' hop, ``fanouts[1]`` to the
-        next hop (paper: fanout {10, 25})."""
+        next hop (paper: fanout (10, 25))."""
         self.graph = graph
         self.fanouts = tuple(int(f) for f in fanouts)
 

@@ -54,15 +54,16 @@ def _train_step(trainer, minibatches) -> float:
     The features stay in the trainer's device table
     (:meth:`DistributedTrainer.feature_table`, uploaded at the first
     step). Per trainer, in order: its int32 id blocks are built on the
-    host (``train.gather``), uploaded with its labels (``train.upload``),
-    and ``sage_grads`` gathers their rows from the table and runs on
-    them, with the loss read back (``train.grads``). Then the gradients
+    host (``train.gather``, which counts their ids as
+    ``train.rows_gathered``), uploaded with its labels
+    (``train.upload``), and the trainer's model (``sage_grads`` or
+    ``gat_grads``) gathers their rows from the table and runs on them,
+    with the loss read back (``train.grads``). Then the gradients
     are summed in trainer order, averaged and applied (``train.update``);
     all of it inside one ``train`` span. While a session is active the
     upload and the update wait for their arrays, so each span holds its
     own transfer or device work; with telemetry off nothing waits.
     """
-    from ..gnn import sage
     from ..gnn.train import index_blocks
 
     P = trainer.parts.num_parts
@@ -74,7 +75,8 @@ def _train_step(trainer, minibatches) -> float:
         for mb in minibatches:
             with tel.span("train.gather") as sp:
                 host = index_blocks(mb)
-                sp.nbytes = sum(x.nbytes for x in host[:3])
+                sp.nbytes = sum(x.nbytes for x in host[:-1])
+                tel.count("train.rows_gathered", sum(x.size for x in host[:-1]))
             with tel.span("train.upload") as sp:
                 batch = jax.device_put(host)
                 if tel.enabled():
@@ -83,10 +85,8 @@ def _train_step(trainer, minibatches) -> float:
                     tel.count("device.h2d_bytes", sp.nbytes)
             with tel.span("train.grads"):
                 *ids, labels = batch
-                loss, g = sage.sage_grads(
-                    trainer.params,
-                    *trainer.feature_rows(table, loc, ids),
-                    labels,
+                loss, g = trainer.model.grads(
+                    trainer.params, trainer.feature_rows(table, loc, ids), labels
                 )
                 # The call holds its inputs until it has run; dropping them
                 # here keeps one trainer's inputs on the device at a time.
@@ -112,14 +112,13 @@ def _final_accuracy(trainer) -> float:
     (up to) 512 training nodes; 0.0 when the run trains no model."""
     if not trainer.train_model:
         return 0.0
-    from ..gnn.sage import sage_accuracy
     from ..gnn.train import index_blocks
 
     batch = trainer.graph.train_nodes[: min(512, len(trainer.graph.train_nodes))]
     minibatch = trainer.sampler.sample(batch, trainer.rng)
     *ids, labels = jax.device_put(index_blocks(minibatch))
     rows = trainer.feature_rows(*trainer.feature_table(), ids)
-    return float(sage_accuracy(trainer.params, *rows, labels))
+    return float(trainer.model.accuracy(trainer.params, rows, labels))
 
 
 def run_vectorized(trainer) -> "RunResult":  # noqa: F821 — see lazy import

@@ -474,6 +474,42 @@ class TestTrainingStep:
         # one float32 loss read back per trainer step
         assert reg["device.d2h_bytes"].total == 4 * P * steps
 
+    @pytest.mark.parametrize(
+        "model",
+        [dict(), dict(model="gat", heads=2, fanouts=(3, 2, 2))],
+        ids=["sage", "gat"],
+    )
+    def test_rows_gathered_are_the_uploaded_ids(self, parts, monkeypatch, model):
+        import repro.gnn.train as train
+
+        kw = dict(TRAIN, **model)
+        t = DistributedTrainer(parts, telemetry=True, **kw)
+        built = []
+        index_blocks = train.index_blocks
+
+        def spy(mb):
+            out = index_blocks(mb)
+            built.append(out)
+            return out
+
+        monkeypatch.setattr(train, "index_blocks", spy)
+        result = t.run()
+        P, steps = t.parts.num_parts, len(result.losses)
+        built = built[:-1]  # the final accuracy's blocks are not a step's
+        ids = sum(sum(x.size for x in blocks[:-1]) for blocks in built)
+        reg = t.last_telemetry.registry
+        assert reg["train.rows_gathered"].total == ids
+        # the seeds and every hop's ids: B (1 + f1 + f1 f2 + ...) a trainer
+        per_trainer, n = kw["batch_size"], kw["batch_size"]
+        for f in kw["fanouts"]:
+            n *= f
+            per_trainer += n
+        assert ids == P * steps * per_trainer
+        # and the uploaded int32 id blocks are those ids, 4 bytes each
+        uploads = [s for s in t.last_telemetry.tracer.spans if s.name == "train.upload"]
+        labels = P * steps * kw["batch_size"]
+        assert sum(s.nbytes for s in uploads) == 4 * (ids + labels)
+
     @pytest.mark.parametrize("runtime", sorted(RUNTIMES))
     def test_profiler_capture_holds_every_span(self, parts, tmp_path, runtime):
         t = DistributedTrainer(parts, telemetry=True, **RUNTIMES[runtime], **TRAIN)
