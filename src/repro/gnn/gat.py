@@ -15,14 +15,26 @@ with one ``W`` shared by sources and targets and ``Lin`` a linear skip
 with bias; ELU follows every layer but the last. The softmax subtracts
 each target's largest logit.
 
+Each layer computes those sums in the reordered order, which is exact:
+the scores need only ``a · W_h x = x · (W_h^T a)``, and the weighted sum
+of projected rows is the projection of the weighted sum of raw rows,
+``Σ_j α_ij W_h x_j = W_h (Σ_j α_ij x_j)``. So ``W_h^T a_src`` and
+``W_h^T a_dst`` are folded into one ``(D, heads)`` matrix each, every
+raw row is scored with them, the raw rows are summed per head with the
+softmax weights, and only each target's sum, ``(n, heads, D)``, is
+projected. The published order projects every row, the deepest hop
+(``B f1 ... fL`` rows) included, into ``heads x C`` channels; this one
+never widens a source row, and does fewer operations unless a head has
+only a few channels (``bench/models/gat.py``'s ``layer_flops`` counts
+both).
+
 The blocks are ``(B,), (B, f1), ..., (B, f1, ..., fL)``: the seeds and
 each hop's ids, dense features or :class:`Rows` of a device table. Each
 hop's rows are laid out with their id axes reversed
 (:meth:`Rows.fanout_major`): hop ``k`` as ``(n_k, F)`` is then, reshaped
 to ``(f_k, n_{k-1}, F)``, the children of hop ``k-1``'s rows in their
 own order, so one gather gives every hop both as targets and as sources.
-Layer ``l`` turns hops ``0..L-l+1`` into hops ``0..L-l``; each hop's
-rows are projected once per layer, as published.
+Layer ``l`` turns hops ``0..L-l+1`` into hops ``0..L-l``.
 """
 
 from __future__ import annotations
@@ -97,21 +109,26 @@ def _hop_rows(x) -> jax.Array:
 def _layer(layer: GatLayer, h: list, last: bool) -> list:
     """One ``GATConv`` plus skip over hops ``h[0..K]``: the outputs of
     hops ``0..K-1``, each hop's children being the next hop's rows."""
+    d = h[0].shape[-1]
     heads, c = layer.att_src.shape
-    proj = [(x @ layer.w).reshape(len(x), heads, c) for x in h]
-    src = [jnp.sum(p * layer.att_src, axis=-1) for p in proj]
+    wh = layer.w.reshape(d, heads, c)
+    u_src = jnp.einsum("dhc,hc->dh", wh, layer.att_src)             # (D, heads)
+    u_dst = jnp.einsum("dhc,hc->dh", wh, layer.att_dst)
+    src = [x @ u_src for x in h]                                    # (n, heads)
     out = []
     for k in range(len(h) - 1):
         n = len(h[k])
-        dst = jnp.sum(proj[k] * layer.att_dst, axis=-1)             # (n, heads)
-        e_self = jax.nn.leaky_relu(src[k] + dst, 0.2)               # (n, heads)
+        dst = h[k] @ u_dst                                          # (n, heads)
+        e_self = jax.nn.leaky_relu(src[k] + dst, 0.2)
         e_kids = jax.nn.leaky_relu(src[k + 1].reshape(-1, n, heads) + dst, 0.2)
         top = jnp.maximum(e_self, jnp.max(e_kids, axis=0))
         a_self = jnp.exp(e_self - top)
         a_kids = jnp.exp(e_kids - top)                              # (f, n, heads)
-        kids = proj[k + 1].reshape(-1, n, heads, c)                 # (f, n, heads, C)
-        agg = a_self[..., None] * proj[k] + jnp.sum(a_kids[..., None] * kids, axis=0)
-        agg = agg / (a_self + jnp.sum(a_kids, axis=0))[..., None]   # (n, heads, C)
+        denom = a_self + jnp.sum(a_kids, axis=0)
+        a_self, a_kids = a_self / denom, a_kids / denom
+        kids = h[k + 1].reshape(-1, n, d)                           # (f, n, D)
+        r = a_self[..., None] * h[k][:, None] + jnp.einsum("fnh,fnd->nhd", a_kids, kids)
+        agg = jnp.einsum("nhd,dhc->nhc", r, wh)                     # (n, heads, C)
         agg = jnp.mean(agg, axis=1) if last else agg.reshape(n, heads * c)
         out.append(agg + layer.bias + h[k] @ layer.skip_w + layer.skip_b)
     return out

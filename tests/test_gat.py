@@ -1,8 +1,9 @@
 """GAT (``gnn/gat.py``) on the CPU at a tiny size, with seeded random
 weights: its loss and gradients against the benchmark's plain reference
 (``bench/models/gat.py``, an independent write-up of the published
-equations), two closed forms of one layer, the published parameter
-count, the L-hop id blocks and the trainer's refusals."""
+equations), one layer against a published-order oracle, two closed
+forms of one layer, the published parameter count, the L-hop id blocks
+and the trainer's refusals."""
 
 from __future__ import annotations
 
@@ -70,16 +71,69 @@ def test_grads_match_the_reference(seed, form):
     )
     # Both sides are float32 with every product at full precision on
     # the CPU; they differ in the order of their sums only (the program
-    # sums the self term apart and normalises after the sum, the
-    # reference softmaxes first), a few float32 roundings (6e-8 each)
-    # deep. Read here: 6e-8 on the loss, 8e-7 of a leaf's largest entry
-    # on its gradient; the tolerances are about ten times those.
+    # sums the self term apart and projects each target's weighted sum
+    # of raw rows, the reference projects every row first), a few
+    # float32 roundings (6e-8 each) deep. Read here: 1.2e-7 on the
+    # loss, 1.24e-6 of a leaf's largest entry on its gradient; the
+    # tolerances are about eight times those.
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
     for name, g in zip(ref.LEAVES, jax.tree_util.tree_leaves(grads)):
         r = np.asarray(ref_grads[name])
         np.testing.assert_allclose(
             np.asarray(g), r, rtol=1e-5, atol=1e-5 * np.abs(r).max(), err_msg=name
         )
+
+
+def _published_layer(layer, h, last):
+    """The oracle: one layer in the published order, in float64: every
+    row projected to ``heads x C``, then scored and summed."""
+    w, a_src, a_dst, bias, skip_w, skip_b = (np.asarray(x, np.float64) for x in layer)
+    heads, c = a_src.shape
+    proj = [np.asarray(x, np.float64) @ w for x in h]
+    proj = [p.reshape(len(p), heads, c) for p in proj]
+    out = []
+    for k in range(len(h) - 1):
+        n = len(h[k])
+        srcs = np.concatenate([proj[k][None], proj[k + 1].reshape(-1, n, heads, c)])
+        e = np.sum(srcs * a_src, -1) + np.sum(proj[k] * a_dst, -1)    # (1+f, n, heads)
+        e = np.where(e > 0, e, 0.2 * e)
+        a = np.exp(e - e.max(0))
+        a /= a.sum(0)
+        agg = np.sum(a[..., None] * srcs, axis=0)                      # (n, heads, C)
+        agg = agg.mean(1) if last else agg.reshape(n, heads * c)
+        out.append(agg + bias + np.asarray(h[k], np.float64) @ skip_w + skip_b)
+    return out
+
+
+@pytest.mark.parametrize("d", [6, 20])
+@pytest.mark.parametrize("fanout", [1, 2, 3])
+@pytest.mark.parametrize("form", ["dense", "rows"])
+@pytest.mark.parametrize("last", [False, True])
+def test_reordered_layer_matches_the_published_order(last, form, fanout, d):
+    # Heads 3 x 4 channels: d = 6 is below heads x C = 12, d = 20 above.
+    heads, c, b = 3, 4, 5
+    rng = np.random.default_rng(fanout * 100 + d)
+    table = rng.normal(size=(40, d)).astype(np.float32)
+    ids = [rng.integers(0, len(table), (b,) + (fanout,) * k) for k in range(3)]
+    if form == "dense":
+        blocks = [jnp.asarray(table[i]) for i in ids]
+    else:
+        padded = jnp.asarray(np.pad(table, ((0, 0), (0, LANES - d))))
+        blocks = [Rows(padded, None, jnp.asarray(i, jnp.int32), d) for i in ids]
+    h = [gat._hop_rows(x) for x in blocks]
+    p = gat.init_gat(jax.random.PRNGKey(fanout), d, c, c, heads=heads, num_layers=1)
+    o = c if last else heads * c
+    bias, skip_w, skip_b = (
+        jnp.asarray(rng.normal(size=s), jnp.float32) for s in [(o,), (d, o), (o,)]
+    )
+    layer = p.layers[0]._replace(bias=bias, skip_w=skip_w, skip_b=skip_b)
+    got = gat._layer(layer, h, last)
+    want = _published_layer(layer, h, last)
+    assert [x.shape for x in got] == [x.shape for x in want] == [(b, o), (b * fanout, o)]
+    # float32 with full-precision products on the CPU against float64:
+    # a few float32 roundings (6e-8 each) over sums of at most 20 terms.
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), r, rtol=1e-5, atol=1e-5 * np.abs(r).max())
 
 
 def _layer_inputs(seed, d=6, n=5, f=3):

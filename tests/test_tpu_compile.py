@@ -201,3 +201,28 @@ def test_gat_grads_gathers_from_the_table_in_place(shape, no_persistent_cache):
         line for line in hlo.splitlines()
         if f"[{N_NODES},{width}]" in line and " copy(" in line
     ]
+
+
+def test_gat_grads_never_projects_the_deepest_hop(shape, no_persistent_cache):
+    """``gat_grads`` at the ``products-gat`` job's shapes (batch 512,
+    fanout [10, 10, 10], F = 100, 4 heads x 128, 47 classes) scores and
+    sums raw rows and projects each target's sum: no instruction holds
+    the 512,000 deepest-hop rows widened to 4 x 128 channels."""
+    from repro.gnn.gat import gat_grads, init_gat
+    from repro.gnn.sage import Rows
+    from repro.gnn.train import LANES
+
+    b, heads, hidden = 512, 4, 128
+    width = -(-F // LANES) * LANES
+    params = jax.tree_util.tree_map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_gat(jax.random.PRNGKey(0), F, hidden, 47, heads)),
+    )
+    table = shape((N_NODES, width), jnp.float32)
+    dims = [(b,), (b, 10), (b, 10, 10), (b, 10, 10, 10)]
+    rows = [Rows(table, None, shape(d), F) for d in dims]
+    hlo = gat_grads.lower(params, rows, shape((b,))).compile().as_text()
+    deepest = b * 10 * 10 * 10
+    widened = (f"[{deepest},{heads * hidden}]", f"[{deepest},{heads},{hidden}]")
+    assert f"[{deepest}," in hlo
+    assert not [line for line in hlo.splitlines() if any(s in line for s in widened)]
